@@ -11,6 +11,7 @@ so the framework works even without a compiler.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -45,13 +46,21 @@ def _compile(src: Path, out: Path) -> None:
         raise
 
 
+def build_path(name: str) -> Path:
+    """Where native/<name>.cpp builds to: keyed on a hash of the source
+    contents, so a copied checkout (whose mtimes say nothing) never
+    loads a library built from other source."""
+    digest = hashlib.sha256((_DIR / f"{name}.cpp").read_bytes()).hexdigest()
+    return _BUILD / f"{name}-{digest[:16]}.so"
+
+
 def _load_lib(name: str) -> Optional[ctypes.CDLL]:
-    """Compile (if stale) and dlopen native/<name>.cpp -> _build/<name>.so."""
-    src = _DIR / f"{name}.cpp"
-    so = _BUILD / f"{name}.so"
+    """Compile (if not built from this source) and dlopen
+    native/<name>.cpp."""
     try:
-        if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
-            _compile(src, so)
+        so = build_path(name)
+        if not so.exists():
+            _compile(_DIR / f"{name}.cpp", so)
         return ctypes.CDLL(str(so))
     except (OSError, subprocess.SubprocessError):
         return None

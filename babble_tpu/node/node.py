@@ -304,6 +304,10 @@ class Node:
         self._committer: Optional[asyncio.Task] = None
         self._consensus_task: Optional[asyncio.Task] = None
         self._consensus_dirty = False
+        #: the first exception the consensus pipeline raised, kept apart
+        #: from sync errors: a failed pipeline is a defect of this node,
+        #: not of a link or a peer, and a driver must be able to fail on it
+        self.consensus_error: Optional[BaseException] = None
 
         self._last_consensus = 0.0
         self._fast_forwarding = False
@@ -2137,9 +2141,14 @@ class Node:
         # worker thread is timed from the awaiting coroutine; phase
         # records inside the span become its children in /debug/spans
         with self.tracer.span("consensus", events=n_events):
-            new_events, phase_timings = await loop.run_in_executor(
-                None, self.core.run_consensus
-            )
+            try:
+                new_events, phase_timings = await loop.run_in_executor(
+                    None, self.core.run_consensus
+                )
+            except Exception as e:
+                if self.consensus_error is None:
+                    self.consensus_error = e
+                raise
             t2 = time.perf_counter()
             for k, v in phase_timings.items():
                 phase = k[: -len("_s")]

@@ -6,10 +6,10 @@ it, and a fleet restart re-paid the whole bill.  Three layers:
 
 1. **Persistent XLA cache** (``configure``): jax's compilation cache
    directory, so a recompile of an already-seen program is a
-   deserialize (sub-second) instead of a full XLA pass.  The cli/
-   testnet already share one directory per fleet; bench and the
-   prewarm path route through here so every surface agrees on the
-   flags.
+   deserialize (sub-second) instead of a full XLA pass.  Every
+   surface routes through here, so one directory serves them all:
+   ``JAX_COMPILATION_CACHE_DIR`` when set, else a fixed path in the
+   checkout, else the caller's fallback (a node's datadir).
 2. **Shape manifest** (``record_shape`` / ``load_manifest``): the
    engine records every live-flush program it actually compiled —
    keyed on the ``DagConfig`` + ``ENGINE_CACHE_VERSION`` + the bucketed
@@ -32,6 +32,7 @@ same-shape flush stream triggers zero of them).
 from __future__ import annotations
 
 import json
+import logging
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -48,6 +49,8 @@ from .state import DagConfig, init_state
 ENGINE_CACHE_VERSION = "9.0"
 
 _MANIFEST = "babble_aot_manifest.json"
+
+log = logging.getLogger("babble_tpu.aot")
 
 # ----------------------------------------------------------------------
 # compile-event counters (jax.monitoring -> obs registries + tests)
@@ -124,17 +127,52 @@ def compile_counts() -> Dict[str, int]:
 # ----------------------------------------------------------------------
 # persistent XLA cache
 
-def configure(cache_dir: str) -> None:
-    """Point jax's persistent compilation cache at ``cache_dir`` (every
-    surface — cli, testnet, bench, prewarm — routes through here so the
-    flags agree).  Idempotent; safe before or after backend init."""
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+def _checkout_cache_dir(module_file: str = __file__) -> Optional[str]:
+    """``<checkout>/.jax_cache`` (listed in .gitignore) when the package
+    runs from a checkout — fixed, because the path is part of what a
+    later process must find again; None in an installed package, whose
+    directory is neither the user's nor writable."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(module_file))))
+    if os.path.isfile(os.path.join(root, "pyproject.toml")):
+        return os.path.join(root, ".jax_cache")
+    return None
+
+
+#: the cache directory when neither the caller nor the environment names
+#: one (None outside a checkout)
+DEFAULT_CACHE_DIR = _checkout_cache_dir()
+
+
+def configure(cache_dir: Optional[str] = None,
+              fallback: Optional[str] = None) -> str:
+    """Point jax's persistent compilation cache at the one chosen
+    directory and return it: an explicit ``cache_dir`` (cli
+    ``--jax_cache``), else ``JAX_COMPILATION_CACHE_DIR``, else
+    ``DEFAULT_CACHE_DIR``, else ``fallback`` (a node's datadir cache).
+    Every surface — cli, testnet nodes, bench, chip_smoke — routes
+    through here so the flags agree.  Returns "" (cache off, with
+    a warning) when there is no directory or it cannot be made.
+    Idempotent; safe before or after backend init."""
+    path = (cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or DEFAULT_CACHE_DIR or fallback)
+    if not path:
+        log.warning("no compile-cache directory: running without one")
+        return ""
+    try:
+        os.makedirs(path, exist_ok=True)
+    except OSError as exc:
+        log.warning("compile cache off: cannot make %s (%s)", path, exc)
+        return ""
+    # when the environment names the directory jax has already read it
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
     # the live-flush latency program is deliberately small — without
     # this floor it would fall under jax's default 1 s minimum and
     # never persist, which is exactly the program we restart for
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     install_listeners()
+    return path
 
 
 # ----------------------------------------------------------------------
@@ -272,7 +310,9 @@ def prewarm_engine(engine, cache_dir: str,
     Returns {"compiled": n, "from_manifest": m}."""
     from . import flush as flush_ops
 
-    configure(cache_dir)
+    # ``cache_dir`` holds the manifest; the caller chose it and pointed
+    # the persistent cache there (``configure``)
+    install_listeners()
     engine._aot_dir = cache_dir
     if hasattr(engine, "pre_size") and hasattr(engine, "k"):
         return _prewarm_fork(engine, cache_dir)

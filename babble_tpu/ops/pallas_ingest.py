@@ -20,7 +20,8 @@ table pads its lane dimension to 128 and lands at 16.7 MB.
 Applicability gates (callers fall back to the level scan otherwise):
 - n <= 64 creators (half-lane packing),
 - seqs < 32767 (int16 coordinates),
-- packed table + index arrays within the VMEM budget (~65k events).
+- packed table + index arrays within the VMEM budget and the index
+  arrays within SMEM (87,039 events).
 
 Reference semantics: InitEventCoordinates (hashgraph.go:399-463), one
 event at a time over the Store — the same recurrence, minus the store
@@ -41,12 +42,20 @@ from .state import I32
 
 _HALF = 64
 _VMEM_BUDGET = 13 * 1024 * 1024
+_SMEM_BYTES = 1 << 20                             # v5e scalar memory
+_SMEM_PAGE = 4096
 
 
 def walk_supported(n: int, e_cap: int, s_cap: int) -> bool:
     table = (e_cap + 2) // 2 * 128 * 2            # packed int16 bytes
     index = 4 * (e_cap + 1) * 4                   # sp/op/creator/seq i32
-    return n <= _HALF and s_cap < 32767 and table + index < _VMEM_BUDGET
+    # sp/op/meta ride in SMEM, each padded to whole 4 KiB pages, with a
+    # page left for the trip count: the described-v5e compiler admits
+    # e_cap 87,039 and refuses 87,040 (tests/test_tpu_compile.py)
+    pages = -(-(e_cap + 1) * 4 // _SMEM_PAGE)
+    smem = (3 * pages + 1) * _SMEM_PAGE
+    return (n <= _HALF and s_cap < 32767 and table + index < _VMEM_BUDGET
+            and smem <= _SMEM_BYTES)
 
 
 def _roll64(row: jnp.ndarray, interpret: bool) -> jnp.ndarray:

@@ -89,13 +89,17 @@ class WideStream:
                  record_ordered: bool = True, stacked: bool = False,
                  mesh=None, registry: Optional[Registry] = None):
         """``stacked=True`` holds la/fd as one [C, E+1, w] array driven
-        by the vmapped stacked kernels; with ``mesh`` (an axis named
-        "p") the block axis is sharded across devices and the cross-
-        block reductions become XLA collectives — the p-sharded window
-        composition the v5e-8 north star needs (blocks are the single-
-        chip stand-in for p-shards, ops/wide.py docstring)."""
+        by the vmapped stacked kernels; with ``mesh`` (any shape, e.g.
+        ``make_mesh(4)``) the block axis is spread over all its devices
+        (``wide.block_sharding``) and the cross-block reductions become
+        XLA collectives — the p-sharded window composition the v5e-8
+        north star needs (blocks are the single-chip stand-in for
+        p-shards, ops/wide.py docstring).  Without ``n_blocks`` the
+        block count rounds up to a multiple of the mesh's devices."""
         self.cfg = cfg
         self.C = n_blocks or block_count(cfg)
+        if n_blocks is None and mesh is not None:
+            self.C = -(-self.C // mesh.size) * mesh.size
         self.round_margin = round_margin
         self.seq_window = seq_window
         self.record_ordered = record_ordered

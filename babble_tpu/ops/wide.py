@@ -622,8 +622,8 @@ def _jits(cfg: DagConfig, C: int):
     # ---------------- stacked twins (sharded streaming) ----------------
     # The same block kernels vmapped over a leading block axis
     # [C, E+1, w]: one jitted program per phase step instead of C host
-    # dispatches, and — with the stacked blocks laid out P("p") over a
-    # device mesh (parallel/sharded.py wide-stream section) — XLA
+    # dispatches, and — with the stacked blocks spread over every device
+    # of a mesh (block_sharding) — XLA
     # partitions each vmapped kernel per-device and turns the
     # cross-block reductions (.sum(0) / .any(0) / reshape-concat) into
     # ICI collectives.  ``offs`` is the per-block column origin,
@@ -740,26 +740,41 @@ def _init_blocks(cfg: DagConfig, C: int):
     return la, fd
 
 
+def block_sharding(mesh):
+    """The stacked blocks' layout on ``mesh``: the block axis spread over
+    EVERY mesh axis, so each device owns C / mesh.size blocks.  Sharding
+    over "p" alone would hold each block once per "ev" device: on the 2x2
+    mesh ``make_mesh(4)`` builds, the 10k strongly-see tally then needs
+    18.33 GB of a chip's 15.75 GB (described-chip compile, PR 21)."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    return NamedSharding(mesh, P(tuple(mesh.axis_names), None, None))
+
+
 def _init_blocks_stacked(cfg: DagConfig, C: int, mesh=None):
-    """Stacked block arrays [C, E+1, w]; with ``mesh`` they are placed
-    P("p", None, None) so each device owns C/p blocks and the stacked
-    kernels run SPMD with XLA-inserted collectives."""
+    """Stacked block arrays [C, E+1, w]; with ``mesh`` they are laid out
+    by ``block_sharding`` and the stacked kernels run SPMD with
+    XLA-inserted collectives."""
     w = _block_width(cfg, C)
     e1 = cfg.e_cap + 1
-    la = jnp.full((C, e1, w), -1, cfg.coord_dtype)
-    fd = jnp.full((C, e1, w), cfg.fd_inf, cfg.coord_dtype)
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
 
-        if C % mesh.shape["p"]:
-            raise ValueError(
-                f"block count C={C} must be a multiple of mesh "
-                f"'p'={mesh.shape['p']}"
-            )
-        sh = NamedSharding(mesh, P("p", None, None))
-        la, fd = jax.device_put(la, sh), jax.device_put(fd, sh)
-    return la, fd
+    def build():
+        return (jnp.full((C, e1, w), -1, cfg.coord_dtype),
+                jnp.full((C, e1, w), cfg.fd_inf, cfg.coord_dtype))
+
+    if mesh is None:
+        return build()
+    if C % mesh.size:
+        raise ValueError(
+            f"block count C={C} must be a multiple of the mesh's "
+            f"{mesh.size} devices"
+        )
+    sh = block_sharding(mesh)
+    # filled in place, shard by shard: building the whole window on the
+    # default device first does not fit there at 10k (a 4-chip run ran
+    # out of device 0's memory placing it, PR 21)
+    return jax.jit(build, out_shardings=(sh, sh))()
 
 
 def _is_stacked(blocks) -> bool:

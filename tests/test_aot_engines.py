@@ -151,3 +151,63 @@ def test_wide_prewarm_compiles_at_boot_and_is_a_semantic_noop(tmp_path):
     _drive(w2, dag)
     assert w1.consensus_events() == w2.consensus_events()
     assert len(w1.consensus_events()) > 0
+
+
+@pytest.fixture
+def restore_cache_config():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    yield
+    jax.config.update("jax_compilation_cache_dir", was[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", was[1])
+    cc.reset_cache()
+
+
+def test_configure_follows_env_else_fixed_checkout_path(
+        tmp_path, monkeypatch, restore_cache_config):
+    """One choice of compile-cache directory for every surface:
+    JAX_COMPILATION_CACHE_DIR when set; otherwise the same path inside
+    the checkout on every call (a path that moves never hits)."""
+    import jax
+
+    env_dir = str(tmp_path / "env_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    assert aot.configure() == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert aot.configure(str(tmp_path / "explicit")) == str(
+        tmp_path / "explicit")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first = aot.configure()
+    assert aot.configure() == first == aot.DEFAULT_CACHE_DIR
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert os.path.dirname(first) == root
+    assert jax.config.jax_compilation_cache_dir == first
+
+
+def test_configure_outside_a_checkout(tmp_path, monkeypatch,
+                                      restore_cache_config):
+    """An installed package has no checkout: no default directory, the
+    caller's fallback (a node's datadir) is used, and a directory that
+    cannot be made turns the cache off instead of failing the start."""
+    import jax
+
+    site = tmp_path / "site-packages"
+    module = site / "babble_tpu" / "ops" / "aot.py"
+    assert aot._checkout_cache_dir(str(module)) is None
+    (site / "pyproject.toml").parent.mkdir(parents=True)
+    (site / "pyproject.toml").write_text("")
+    assert aot._checkout_cache_dir(str(module)) == str(site / ".jax_cache")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(aot, "DEFAULT_CACHE_DIR", None)
+    datadir_cache = str(tmp_path / "datadir" / "jax_cache")
+    assert aot.configure(fallback=datadir_cache) == datadir_cache
+    assert jax.config.jax_compilation_cache_dir == datadir_cache
+    blocked = tmp_path / "a_file"
+    blocked.write_text("")
+    assert aot.configure(str(blocked / "cache")) == ""
+    assert aot.configure() == ""

@@ -186,3 +186,20 @@ def test_walk_mode_matches_fast():
     fast = jax.jit(lambda b: consensus_step_impl(cfg, "fast", init_state(cfg), b))(batch)
     walk = jax.jit(lambda b: consensus_step_impl(cfg, "walk", init_state(cfg), b))(batch)
     assert_consensus_parity(fast, walk, e, "walk-vs-fast")
+
+
+def test_native_build_keyed_on_source_contents(tmp_path, monkeypatch):
+    """A copied checkout carries mtimes that say nothing: the library is
+    rebuilt whenever the .cpp contents change, never reused."""
+    from babble_tpu import native
+
+    monkeypatch.setattr(native, "_DIR", tmp_path)
+    monkeypatch.setattr(native, "_BUILD", tmp_path / "_build")
+    src = tmp_path / "probe.cpp"
+    src.write_text('extern "C" int probe() { return 1; }\n')
+    first = native.build_path("probe")
+    assert native.build_path("probe") == first
+    assert native._load_lib("probe").probe() == 1
+    src.write_text('extern "C" int probe() { return 2; }\n')
+    assert native.build_path("probe") != first
+    assert native._load_lib("probe").probe() == 2

@@ -462,3 +462,28 @@ def test_bootstrap_replays_local_tail_or_refuses():
     assert d0.hg is old_engine and d0.head == old_head
     for ev, ti in old_ti:
         assert ev.topological_index == ti, "gossip sort keys must survive"
+
+
+def test_consensus_failure_is_recorded_apart_from_sync_errors():
+    """A pipeline exception is kept on the node (the first one), so a
+    driver fails on it instead of reading a sync-error count."""
+    async def go():
+        net = InmemNetwork()
+        key = generate_key()
+        t = net.transport()
+        peers = [Peer(net_addr=t.local_addr(), pub_key_hex=key.pub_hex)]
+        node = Node(Config.test_config(), key, peers, t, InmemAppProxy())
+        node.init()
+        boom = RuntimeError("device pipeline failed")
+
+        def fail():
+            raise boom
+
+        node.core.run_consensus = fail
+        with pytest.raises(RuntimeError):
+            async with node.core_lock:
+                await node._run_consensus_locked(0)
+        assert node.consensus_error is boom
+        await node.shutdown()
+
+    asyncio.run(go())

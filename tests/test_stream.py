@@ -141,3 +141,25 @@ def test_stream_stacked_sharded_parity():
                                compact_min=64, mesh=mesh)
     assert stream2.evicted > 0, "compaction never engaged (sharded)"
     _assert_stream_matches(stream2, out, e)
+
+
+def test_stream_spreads_blocks_over_every_mesh_device():
+    """On the 2-D mesh ``make_mesh`` builds by default (here 4x2) the
+    stream spreads its blocks over all 8 devices, one block each, not
+    over "p" alone with a copy per "ev" row, and still matches the
+    fused pipeline."""
+    from babble_tpu.parallel.mesh import make_mesh
+
+    n, e = 24, 2800
+    dag = random_gossip_arrays(n, e, seed=13)
+    _, out = _fused_reference(n, e, dag)
+    cfg = DagConfig(n=n, e_cap=1400, s_cap=110, r_cap=16)
+    mesh = make_mesh(8)
+    assert mesh.shape["ev"] > 1
+    stream = stream_consensus(cfg, dag, batch_events=350, round_margin=0,
+                              seq_window=16, compact_min=64, mesh=mesh)
+    shards = stream.la_blocks.addressable_shards
+    assert stream.C == 8
+    assert {s.device for s in shards} == set(mesh.devices.flat)
+    assert all(s.data.shape[0] == 1 for s in shards)
+    _assert_stream_matches(stream, out, e)
