@@ -22,6 +22,12 @@ Boundaries of the design:
 - **No clock games.**  ``start`` is epoch wall time (cross-node
   alignment in a fleet dump), ``dur_s`` is measured with
   ``perf_counter``.
+- **On the profiler's clock too.**  :meth:`span` also opens a
+  ``jax.profiler.TraceAnnotation`` named ``babble_<name>`` (a no-op
+  unless a profiler trace runs), so a trace shows the node's
+  ``push`` / ``gossip`` / ``consensus`` spans beside the device's
+  operations.  Spans recorded after the fact with :meth:`record` stay
+  in the ring only.  Where jax is absent the ring is all there is.
 """
 
 from __future__ import annotations
@@ -33,8 +39,19 @@ import itertools
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Iterator, List, Optional
+
+
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is absent
+    (this package is stdlib-only by contract: imported on first use)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class SpanTracer:
@@ -53,15 +70,19 @@ class SpanTracer:
     @contextmanager
     def span(self, name: str, **attrs) -> Iterator[int]:
         """Record the enclosed block as a span; nested spans (same task)
-        become children."""
+        become children.  A profiler trace running meanwhile holds it as
+        ``babble_<name>``."""
         parent = self._current.get()
         sid = next(self._ids)
+        annotation = _trace_annotation()
         t_wall = time.time()
         t0 = time.perf_counter()
         token = self._current.set(sid)
         error: Optional[str] = None
         try:
-            yield sid
+            with (annotation("babble_" + name) if annotation
+                  else nullcontext()):
+                yield sid
         except BaseException as e:
             error = type(e).__name__
             raise

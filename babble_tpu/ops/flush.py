@@ -412,11 +412,14 @@ def live_flush_impl(
     (k=0, the drain call when gossip stops): the ingest phases are
     no-ops on padded lanes and fame/order still advance.
 
-    The ``named_scope`` regions carry phase attribution into device
-    profiles (xprof/tensorboard via /debug/trace): HLO ops inherit the
-    scope name, so a trace of the single fused launch still splits its
-    device time ingest/fame/order.  Pure metadata — the compiled
-    numerics are bit-identical with or without them."""
+    The ``named_scope`` regions carry phase attribution into the
+    compiled program: HLO instructions inherit the scope name in their
+    ``op_name`` metadata (ingest's nested ``la`` / ``fd`` / ``rounds``
+    among them).  The v5e trace's operations carry only the instruction
+    name, so a trace of the single fused launch splits its device time
+    ingest/fame/order once joined with the program's text
+    (``benchmark/scopes.py``).  Pure metadata — the compiled numerics
+    are bit-identical with or without them."""
     with jax.named_scope("babble_ingest"):
         state = ingest_coords_impl(cfg, state, "incremental", batch)
         state = ingest_rounds_impl(cfg, state, "incremental", batch)

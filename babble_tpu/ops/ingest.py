@@ -638,7 +638,11 @@ def ingest_coords_impl(
     cfg: DagConfig, state: DagState, fd_mode: str, batch: EventBatch
 ) -> DagState:
     """Phase 1 of ingest: write batch fields and fill the la/fd
-    coordinate tensors (everything before round assignment)."""
+    coordinate tensors (everything before round assignment).
+
+    The last-ancestor fill runs under ``named_scope("la")`` and the
+    first-descendant fill under ``named_scope("fd")``, whichever form
+    the mode takes, so a profile splits ingest's device time by fill."""
     state = _write_batch_fields(state, cfg, batch)
 
     def _fd_batch(state, slot_sched):
@@ -655,33 +659,40 @@ def ingest_coords_impl(
 
         assert walk_supported(cfg.n, cfg.e_cap, cfg.s_cap), cfg
         interpret = jax.default_backend() != "tpu"
-        packed = la_walk(
-            cfg.e_cap, cfg.n, state.sp, state.op, state.creator,
-            state.seq, state.n_events, interpret,
-        )
-        state = state._replace(
-            la=unpack_la(cfg.e_cap, cfg.n, packed, state.n_events)
-            .astype(cfg.coord_dtype)
-        )
+        with jax.named_scope("la"):
+            packed = la_walk(
+                cfg.e_cap, cfg.n, state.sp, state.op, state.creator,
+                state.seq, state.n_events, interpret,
+            )
+            state = state._replace(
+                la=unpack_la(cfg.e_cap, cfg.n, packed, state.n_events)
+                .astype(cfg.coord_dtype)
+            )
         state = _fd_init_own(state, cfg, batch)
         slot_sched = _slot_sched(state.n_events - batch.k, cfg, batch.sched)
-        return _reset_coord_sentinels(_fd_batch(state, slot_sched), cfg)
+        with jax.named_scope("fd"):
+            state = _fd_batch(state, slot_sched)
+        return _reset_coord_sentinels(state, cfg)
     if fd_mode == "absorb":
-        state = _la_init_direct(state, cfg, batch)
-        state = _la_absorb(state, cfg)
+        with jax.named_scope("la"):
+            state = _la_absorb(_la_init_direct(state, cfg, batch), cfg)
         state = _fd_init_own(state, cfg, batch)
-        return _reset_coord_sentinels(_fd_full(state, cfg), cfg)
+        with jax.named_scope("fd"):
+            state = _fd_full(state, cfg)
+        return _reset_coord_sentinels(state, cfg)
     slot_sched = _slot_sched(state.n_events - batch.k, cfg, batch.sched)
-    state = _la_level_scan(state, cfg, slot_sched)
+    with jax.named_scope("la"):
+        state = _la_level_scan(state, cfg, slot_sched)
     state = _fd_init_own(state, cfg, batch)
-    if fd_mode == "incremental":
-        state = _fd_incremental(state, cfg, batch)
-    elif fd_mode == "fast":
-        # batch path: the schedule covers the whole DAG, so the cheaper
-        # of reverse scan / compare-count applies (see _fd_batch)
-        state = _fd_batch(state, slot_sched)
-    else:
-        state = _fd_full(state, cfg)
+    with jax.named_scope("fd"):
+        if fd_mode == "incremental":
+            state = _fd_incremental(state, cfg, batch)
+        elif fd_mode == "fast":
+            # batch path: the schedule covers the whole DAG, so the
+            # cheaper of reverse scan / compare-count applies (_fd_batch)
+            state = _fd_batch(state, slot_sched)
+        else:
+            state = _fd_full(state, cfg)
     return _reset_coord_sentinels(state, cfg)
 
 
@@ -692,17 +703,19 @@ def ingest_rounds_impl(
     Composes with ingest_coords_impl; split so the 10k-participant
     configs can run each phase as its own program (la/fd then cross the
     boundary as donated arguments instead of XLA remat-copy temps —
-    one such copy was 3.8 GB at 10k x 100k)."""
-    if fd_mode in ("walk", "absorb", "fast"):
-        state = _rounds_frontier(state, cfg)
-    else:
-        slot_sched = _slot_sched(
-            state.n_events - batch.k, cfg, batch.sched
-        )
-        state = _rounds_level_scan(state, cfg, slot_sched, batch.sched)
-    # the rounds phase rewrote the witness tables: refresh the packed
-    # per-round bitplanes (derived caches — see state.repack_round_bits)
-    return repack_round_bits(cfg, _reset_round_sentinels(state, cfg))
+    one such copy was 3.8 GB at 10k x 100k).  Runs under
+    ``named_scope("rounds")``."""
+    with jax.named_scope("rounds"):
+        if fd_mode in ("walk", "absorb", "fast"):
+            state = _rounds_frontier(state, cfg)
+        else:
+            slot_sched = _slot_sched(
+                state.n_events - batch.k, cfg, batch.sched
+            )
+            state = _rounds_level_scan(state, cfg, slot_sched, batch.sched)
+        # the rounds phase rewrote the witness tables: refresh the packed
+        # per-round bitplanes (derived caches — see state.repack_round_bits)
+        return repack_round_bits(cfg, _reset_round_sentinels(state, cfg))
 
 
 def ingest_impl(cfg: DagConfig, state: DagState, fd_mode: str, batch: EventBatch) -> DagState:

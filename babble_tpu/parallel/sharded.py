@@ -111,11 +111,17 @@ def consensus_step_impl(
     invariant of fresh batch states, which lets wide-N fame use the
     one-hot MXU strongly-see (ops/ss.py).  A rolled-window caller (none
     exists today — the live engine drives its own phase calls with
-    batch_window=False) MUST pass False here or wide-N fame miscounts."""
-    state = ingest_impl(cfg, state, fd_mode, batch)
-    state = decide_fame_auto_impl(cfg, state, batch_window)
-    state = decide_order_impl(cfg, state)
-    return state
+    batch_window=False) MUST pass False here or wide-N fame miscounts.
+
+    The phases run under the live flush's ``named_scope`` names, which
+    reach the compiled program's ``op_name`` metadata (the v5e trace's
+    operations carry none: ``benchmark/scopes.py`` joins the two)."""
+    with jax.named_scope("babble_ingest"):
+        state = ingest_impl(cfg, state, fd_mode, batch)
+    with jax.named_scope("babble_fame"):
+        state = decide_fame_auto_impl(cfg, state, batch_window)
+    with jax.named_scope("babble_order"):
+        return decide_order_impl(cfg, state)
 
 
 def make_sharded_step(cfg: DagConfig, mesh: Mesh, fd_mode: str = "full"):

@@ -3,8 +3,9 @@ math, exposition format, span trees, loop-lag probe.
 
 Deliberately cheap: no JAX device work anywhere in this module (the
 registry/tracer are stdlib-only by contract), so the tier-1 cost is
-milliseconds.  The live-node integration surface (/metrics on a real
-Service) is covered in test_service_debug.py.
+milliseconds, besides one short host-only profiler trace (a span on
+the profiler's clock).  The live-node integration surface (/metrics on
+a real Service) is covered in test_service_debug.py.
 """
 
 import asyncio
@@ -280,6 +281,29 @@ def test_traced_decorator_sync_and_async():
     names = {s["name"] for s in tr.dump()}
     assert "custom" in names
     assert any("sync_fn" in n for n in names)
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """A span taken under a profiler trace is a host event named
+    ``babble_<name>``; one recorded after the fact stays ring-only."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("gossip"):
+            pass
+        tr.record("consensus", 0.001)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    host = {ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert "babble_gossip" in host
+    assert "babble_consensus" not in host
+    assert {s["name"] for s in tr.dump()} == {"gossip", "consensus"}
 
 
 def test_concurrent_tasks_get_separate_parents():

@@ -121,6 +121,28 @@ def test_fused_step_compiles_on_chip_branches(one_chip, chip_compile):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
+def test_fused_step_carries_its_phase_scopes(one_chip, chip_compile):
+    """The sim step compiled for the chip at a testnet-like 4 x 4,096
+    keeps every phase scope in its instructions' ``op_name`` metadata,
+    where a profile's operations are joined with their phase."""
+    import re
+
+    from babble_tpu.cli import sim_step
+    from babble_tpu.ops.state import init_state
+    from babble_tpu.sim.arrays import batch_from_arrays, random_gossip_arrays
+
+    dag = random_gossip_arrays(4, 4096, seed=7)
+    cfg, step = sim_step(dag, 512)
+    text = step.lower(
+        _abstract(jax.eval_shape(lambda: init_state(cfg)), one_chip),
+        _abstract(batch_from_arrays(dag), one_chip),
+    ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for path in ("babble_ingest/la/", "babble_ingest/fd/",
+                 "babble_ingest/rounds/", "babble_fame/", "babble_order/"):
+        assert any(path in n for n in names), path
+
+
 def test_wide_onehot_strongly_see_compiles_at_10k(one_chip, chip_compile):
     """The wide engine's per-block strongly-see partial at n = 10,000
     takes the int8 one-hot matmul on the chip, and compiles there."""
