@@ -22,6 +22,13 @@ Decisions are sticky (see oracle.py divergence note 1): all deciding voters
 provably agree within a round (two supermajorities of the same witness set
 overlap), so decision order is immaterial.
 
+The scan stops at the first d where no undecided in-window witness has a
+voting round i + d left.  Both that set and the rows able to vote only
+shrink as d grows, so no later step could change a decision: the early
+exit is exact.  Every round decides within a few voting rounds, so the
+scan takes a handful of steps where its bound, max_round - lcr, is the
+DAG's whole round count on a fresh state.
+
 After voting, the last-consensus-round advances to the highest round in the
 window whose witnesses are all decided (hashgraph.go:654-673).
 """
@@ -68,6 +75,23 @@ def decide_fame_impl(cfg: DagConfig, state: DagState,
     seed 1).  The live engine runs gated; whole-DAG batch/sim paths
     keep the ungated reference semantics (every witness has arrived by
     construction, so the gate would only defer the top rounds)."""
+    famous, _ = diagonal_vote_scan(cfg, state, gate)
+    famous_out = state.famous.at[:cfg.r_cap].set(famous)
+    # fame rewrote the famous table: refresh the packed bitplanes so
+    # the order phase's popcount reception tallies read fresh lanes
+    return repack_round_bits(cfg, state._replace(
+        famous=famous_out,
+        lcr=fame_advance_lcr(cfg, state, famous_out, gate),
+    ))
+
+
+def diagonal_vote_scan(cfg: DagConfig, state: DagState, gate: bool = False):
+    """The diagonal vote recursion of ``decide_fame_impl``.
+
+    Returns ``(famous, steps)``: the decided ``famous[:r_cap]`` rows and
+    the number of diagonal steps taken, at most ``d_max - 1``.  The scan
+    runs while some undecided in-window witness still has a voting round
+    (module docstring)."""
     n, r_cap, sm = cfg.n, cfg.r_cap, cfg.super_majority
     R = r_cap
 
@@ -111,11 +135,22 @@ def decide_fame_impl(cfg: DagConfig, state: DagState,
     if gate:
         in_window = in_window & (i_idx <= head_round_min_math(cfg, state))
 
-    def step(d, carry):
-        votes, famous = carry
-        d = jnp.asarray(d, I32)  # fori_loop counter is i64 under x64
+    def open_rows(d, famous):
         # voting round j = i + d exists only while j <= max_round
         can_vote = (i_idx + d) <= state.max_round                   # [R]
+        undecided = (famous == FAME_UNDEFINED) & valid_w & in_window[:, None]
+        return can_vote, undecided
+
+    d_max = jnp.maximum(state.max_round - jnp.maximum(state.lcr, -1), 2)
+
+    def cond(carry):
+        d, _, famous = carry
+        can_vote, undecided = open_rows(d, famous)
+        return (d <= d_max) & (undecided & can_vote[:, None]).any()
+
+    def step(carry):
+        d, votes, famous = carry
+        can_vote, undecided = open_rows(d, famous)
 
         z = jnp.zeros((), I32)
         ss_d = jax.lax.dynamic_slice(ss_pad, (d - 1, z, z), (R, n, n))
@@ -130,7 +165,6 @@ def decide_fame_impl(cfg: DagConfig, state: DagState,
         t = jnp.maximum(yays, nays)
         strong = t >= sm                                            # [R, N, N]
 
-        undecided = (famous == FAME_UNDEFINED) & valid_w & in_window[:, None]
         # coin-round period = number of real participants (hashgraph.go:643)
         normal = (d % cfg.active_n) != 0
 
@@ -146,30 +180,13 @@ def decide_fame_impl(cfg: DagConfig, state: DagState,
         coin_vote = jnp.where(strong, v, mb_d[:, :, None])
         new_votes = jnp.where(normal, v, coin_vote).astype(F32)
         votes = jnp.where(can_vote[:, None, None], new_votes, votes)
-        return votes, famous
+        return d + 1, votes, famous
 
-    d_max = jnp.maximum(state.max_round - jnp.maximum(state.lcr, -1), 2)
-    votes0 = see_next
-    votes, famous = jax.lax.fori_loop(
-        2, d_max + 1, step, (votes0, state.famous[:R])
+    d0 = jnp.asarray(2, I32)
+    d, _, famous = jax.lax.while_loop(
+        cond, step, (d0, see_next, state.famous[:R])
     )
-
-    # advance last consensus round: highest window round with all witnesses
-    # decided (matching the reference's ascending set-on-each-decided-i loop)
-    decided_round = ((~valid_w) | (famous != FAME_UNDEFINED)).all(axis=1)
-    has_w = valid_w.any(axis=1)
-    cand = _lcr_candidates(
-        state, i_idx, in_window, decided_round, has_w, gate
-    )
-    new_lcr = jnp.max(jnp.where(cand, i_idx, -1))
-    lcr = jnp.maximum(state.lcr, new_lcr)
-
-    famous_out = state.famous.at[:R].set(famous)
-    # fame rewrote the famous table: refresh the packed bitplanes so
-    # the order phase's popcount reception tallies read fresh lanes
-    return repack_round_bits(
-        cfg, state._replace(famous=famous_out, lcr=lcr)
-    )
+    return famous, d - d0
 
 
 def _lcr_candidates(state, i_idx, in_window, decided_round, has_w,
@@ -242,7 +259,8 @@ def decide_fame_block_impl(
       are 0/1 and counts stay < 2^24, so it is exact.
 
     Voting for round i stops as soon as all its witnesses are decided
-    (the diagonal scan keeps computing masked steps); fame decisions are
+    (the diagonal scan steps all rounds at once, masking the decided
+    ones, until the last open round decides); fame decisions are
     sticky, so outputs are bit-identical (differentially tested against
     decide_fame_impl and the oracle).
 
@@ -364,7 +382,8 @@ def fame_vote_math(
 def fame_advance_lcr(cfg: DagConfig, state: DagState, famous_out,
                      gate: bool = False):
     """Advance last consensus round: highest window round with all
-    witnesses decided (same reduction as the diagonal scan)."""
+    witnesses decided (matching the reference's ascending
+    set-on-each-decided-i loop, hashgraph.go:654-673)."""
     R = cfg.r_cap
     wsl = state.wslot[:R]
     valid_w = wsl >= 0

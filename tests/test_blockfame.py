@@ -7,7 +7,8 @@ tests pin the blockwise replacements to the originals bit-for-bit:
 - ss_counts_onehot (int8 MXU formulation) == ss_counts_compare on
   adversarial value patterns (sentinels, INF, out-of-band),
 - decide_fame_block_impl == decide_fame_impl across random gossip DAGs
-  (consensus-observable parity, including lcr),
+  (consensus-observable parity, including lcr), and the diagonal scan's
+  early exit stops within a few steps of a DAG's hundreds of rounds,
 - the chunked decide_order median path == the unchunked one.
 """
 
@@ -22,6 +23,7 @@ from babble_tpu.ops import ingest as ingest_ops
 from babble_tpu.ops.fame import (
     decide_fame_block_impl,
     decide_fame_impl,
+    diagonal_vote_scan,
     fame_mode,
 )
 from babble_tpu.ops.order import decide_order_impl
@@ -32,7 +34,11 @@ from babble_tpu.ops.state import (
     assert_consensus_parity,
     init_state,
 )
-from babble_tpu.sim.arrays import batch_from_arrays, random_gossip_arrays
+from babble_tpu.sim.arrays import (
+    ArrayDag,
+    batch_from_arrays,
+    random_gossip_arrays,
+)
 
 
 def _ref_counts(la, fd):
@@ -94,6 +100,84 @@ def test_blockwise_fame_parity(n, e, r_cap, seed):
     blk = jax.jit(functools.partial(run, decide_fame_block_impl))()
     assert_consensus_parity(ref, blk, e, label=f"blockfame n={n}")
     assert int(ref.lcr) >= 0 or e < 100  # the DAGs actually decide fame
+
+
+# 4 validators x 2,048 events: some 150 rounds, so the scan's old bound
+# max_round - lcr is ~150 steps; one compiled program for every seed
+GOSSIP4 = DagConfig(n=4, e_cap=2048, s_cap=1024, r_cap=256)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _fame_order(cfg, fame_fn, gate, st):
+    return decide_order_impl(cfg, fame_fn(cfg, st, gate=gate))
+
+
+_scan = jax.jit(diagonal_vote_scan, static_argnums=(0, 2))
+
+
+@functools.lru_cache(maxsize=4)
+def _gossip4_ingested(seed):
+    batch = batch_from_arrays(random_gossip_arrays(4, GOSSIP4.e_cap, seed))
+    return jax.jit(functools.partial(
+        ingest_ops.ingest_impl, GOSSIP4, init_state(GOSSIP4), "fast"
+    ))(batch)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 5, 6])
+def test_diagonal_fame_early_exit_parity(seed):
+    """The early-exit diagonal scan against the round-serial block form:
+    same famous, lcr, rr and cts, in a few steps of a ~150-round DAG."""
+    st = _gossip4_ingested(seed)
+    diag = _fame_order(GOSSIP4, decide_fame_impl, False, st)
+    blk = _fame_order(GOSSIP4, decide_fame_block_impl, False, st)
+    assert_consensus_parity(blk, diag, GOSSIP4.e_cap,
+                            label=f"early-exit fame seed={seed}")
+    _, steps = _scan(GOSSIP4, st, False)
+    assert int(st.max_round) >= 100
+    assert int(diag.lcr) >= int(st.max_round) - 3
+    assert 2 <= int(steps) <= 8
+
+
+def test_diagonal_fame_exit_waits_through_coin_round():
+    """Seed 5 has a round still open after the coin round (d = 4 with
+    four validators): the scan must go on voting past it."""
+    _, steps = _scan(GOSSIP4, _gossip4_ingested(5), False)
+    assert int(steps) >= 5
+
+
+def _dag_part(dag, lo, hi):
+    """Events [lo, hi) of a DAG as one ingest batch (parent slots stay
+    absolute, levels restart at 0)."""
+    lv = dag.levels[lo:hi]
+    return batch_from_arrays(ArrayDag(
+        dag.n, dag.sp[lo:hi], dag.op[lo:hi], dag.creator[lo:hi],
+        dag.seq[lo:hi], dag.ts[lo:hi], dag.mbit[lo:hi], lv - lv.min(),
+        dag.seed,
+    ))
+
+
+def test_diagonal_fame_gated_second_batch():
+    """Gated fame on a state with lcr >= 0 (the live engine's case): a
+    second batch ingested after a first fame pass decides the same under
+    the early-exit scan as under the block form."""
+    dag = random_gossip_arrays(4, GOSSIP4.e_cap, seed=3)
+    half = GOSSIP4.e_cap // 2
+    ingest = jax.jit(functools.partial(
+        ingest_ops.ingest_impl, GOSSIP4, fd_mode="incremental"
+    ))
+    block = functools.partial(decide_fame_block_impl, batch_window=False)
+    outs = []
+    for fame_fn in (decide_fame_impl, block):
+        st = ingest(init_state(GOSSIP4), batch=_dag_part(dag, 0, half))
+        st = _fame_order(GOSSIP4, fame_fn, True, st)
+        lcr1 = int(st.lcr)
+        assert lcr1 >= 0
+        st = ingest(st, batch=_dag_part(dag, half, GOSSIP4.e_cap))
+        st = _fame_order(GOSSIP4, fame_fn, True, st)
+        assert int(st.lcr) > lcr1
+        outs.append(st)
+    assert_consensus_parity(outs[1], outs[0], GOSSIP4.e_cap,
+                            label="gated early-exit fame, second batch")
 
 
 def test_fame_mode_dispatch():
