@@ -400,38 +400,71 @@ def cmd_run(args) -> int:
 
 def sim_step(dag, r_cap: int, mode: str = "fast"):
     """The batch path of ``sim``: the capacities for an ArrayDag and the
-    fused whole-DAG consensus step over them.  Returns ``(cfg, step)``;
-    ``step(init_state(cfg), batch_from_arrays(dag))`` runs it."""
+    whole-DAG consensus step over them.  Returns ``(cfg, step)``;
+    ``step(*sim_inputs(dag, cfg))`` runs it.
+
+    A DAG that holds an equivocation (``dag.branch_slots`` above 1)
+    runs the fork-aware pipeline (``ops.forks.fork_pipeline_impl``, as
+    ``ForkHashgraph`` does live) with that many branch slots per
+    creator; any other runs the fused ingest + fame + order step in
+    ingest ``mode``."""
     import functools
 
     import jax
 
+    s_cap = max(64, dag.max_chain + 1)
+    if dag.branch_slots > 1:
+        from .ops.forks import ForkConfig, fork_pipeline_impl
+
+        cfg = ForkConfig(n=dag.n, k=dag.branch_slots, e_cap=dag.n_events,
+                         s_cap=s_cap, r_cap=r_cap)
+        return cfg, jax.jit(functools.partial(fork_pipeline_impl, cfg))
+
     from .ops.state import DagConfig
     from .parallel.sharded import consensus_step_impl
 
-    cfg = DagConfig(
-        n=dag.n, e_cap=dag.n_events,
-        s_cap=max(64, dag.max_chain + 1), r_cap=r_cap,
-    )
+    cfg = DagConfig(n=dag.n, e_cap=dag.n_events, s_cap=s_cap, r_cap=r_cap)
     return cfg, jax.jit(functools.partial(consensus_step_impl, cfg, mode))
+
+
+def sim_inputs(dag, cfg, sched_rows: int = 0) -> tuple:
+    """The arguments of ``sim_step``'s step for ``dag``: a fresh state
+    and the event batch, or the fork batch alone.  A ``sched_rows`` pads
+    the level schedule to that many rows, each as wide as a level can be
+    (one event per creator, or per branch column), so that DAGs of equal
+    sizes run one compiled program."""
+    from .ops.forks import ForkConfig
+    from .sim.arrays import (
+        batch_from_arrays, fork_batch_from_arrays, pad_schedule,
+    )
+
+    if isinstance(cfg, ForkConfig):
+        return (fork_batch_from_arrays(dag, cfg, sched_rows),)
+    import jax.numpy as jnp
+    import numpy as np
+
+    from .ops.state import init_state
+
+    batch = batch_from_arrays(dag)
+    sched = pad_schedule(np.asarray(batch.sched), sched_rows, dag.n)
+    return init_state(cfg), batch._replace(sched=jnp.asarray(sched))
 
 
 def cmd_sim(args) -> int:
     import jax
     import numpy as np
 
-    from .ops.state import init_state
-    from .sim.arrays import batch_from_arrays, random_gossip_arrays
+    from .sim.arrays import random_gossip_arrays
 
     t0 = time.perf_counter()
     dag = random_gossip_arrays(args.nodes, args.events, seed=args.seed)
-    batch = batch_from_arrays(dag)
     cfg, step = sim_step(dag, args.rounds)
+    inputs = sim_inputs(dag, cfg)
     print(f"host build: {time.perf_counter()-t0:.2f}s "
           f"(native={__import__('babble_tpu.native', fromlist=['x']).available()})",
           file=sys.stderr)
     t0 = time.perf_counter()
-    out = step(init_state(cfg), batch)
+    out = step(*inputs)
     jax.block_until_ready(out)
     compile_s = time.perf_counter() - t0
     if args.profile:
@@ -439,11 +472,11 @@ def cmd_sim(args) -> int:
         # pprof on its HTTP listener, cmd/main.go:26; the TPU equivalent
         # is a jax profiler trace viewable in tensorboard/xprof)
         with jax.profiler.trace(args.profile):
-            out = step(init_state(cfg), batch)
+            out = step(*inputs)
             jax.block_until_ready(out)
         print(f"profile written to {args.profile}", file=sys.stderr)
     t0 = time.perf_counter()
-    out = step(init_state(cfg), batch)
+    out = step(*inputs)
     jax.block_until_ready(out)
     run_s = time.perf_counter() - t0
     ordered = int(np.count_nonzero(np.asarray(out.rr)[: args.events] >= 0))

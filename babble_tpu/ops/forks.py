@@ -122,6 +122,8 @@ class ForkOut(NamedTuple):
     cts: jnp.ndarray      # i64[E+1]
     max_round: jnp.ndarray
     lcr: jnp.ndarray
+    closure_steps: jnp.ndarray  # i32: descent-closure iterations, all rounds
+    vote_steps: jnp.ndarray     # i32: diagonal vote steps fame ran
 
 
 # ----------------------------------------------------------------------
@@ -139,124 +141,87 @@ class ParentUnknownError(ValueError):
     insert failures by type, not message substring)."""
 
 
-@dataclass
-class ForkDag:
-    """Host index for byzantine mode: assigns branch columns, builds the
-    chain views + common-prefix matrix the kernels need."""
+class _BranchLayout:
+    """ForkDag's branch rules, shared by the live index (``ForkDag``,
+    event by event) and a recorded DAG's plain arrays (``ForkArrays``):
+    which branch column an event joins, the per-slot bookkeeping, each
+    column's root->tip chain, the common-prefix matrix and the chain
+    views.  Subclasses provide ``k``, ``b`` and ``_index(slot)`` (an
+    event's chain index)."""
 
-    participants: Dict[str, int]
-    k: int = 2
-
-    events: List[Event] = field(default_factory=list)
-    slot_of: Dict[str, int] = field(default_factory=dict)
-    levels: List[int] = field(default_factory=list)
-    sp_slot: List[int] = field(default_factory=list)
-    op_slot: List[int] = field(default_factory=list)
-    ebr: List[int] = field(default_factory=list)
-    # per branch column: creator, parent branch col (-1), divergence index,
-    # and the slots of OWNED events (the segment past the divergence)
-    br_creator: List[int] = field(init=False)
-    br_parent: List[int] = field(init=False)
-    br_div: List[int] = field(init=False)
-    br_events: List[List[int]] = field(init=False)
-    br_used: List[bool] = field(init=False)
-    # (branch col, index) -> slot, for fork-child attachment
-    _chain_tip: Dict[int, int] = field(default_factory=dict)   # col -> tip slot
-    # per-CREATOR slots in insertion order (the gossip Known/diff view)
-    cr_events: List[List[int]] = field(init=False)
-    # rolling-window seeds (ForkBatch docstring): ABSOLUTE round and
-    # witness trilean per slot, -1 until the pipeline computes them;
-    # r_off = absolute round of window row 0; evicted = total dropped
-    rseed: List[int] = field(default_factory=list)
-    wseed: List[int] = field(default_factory=list)
-    r_off: int = 0
-    evicted: int = 0
-    # effective (clamp-enforced) timestamp per slot — same adversarial-ts
-    # defense as HostDag.eff_ts (core/dag.py TS_CLAMP_WINDOW_NS), derived
-    # at insert from the parents' effective values.  The median kernels
-    # consume these, never the signed claims; a fork's branches clamp
-    # against their own ancestry, so equivocating AND lying about time
-    # buys a byzantine creator nothing extra.
-    eff_ts: List[int] = field(default_factory=list)
-    # absolute chain extent per branch (max index + 1) — survives
-    # eviction, unlike window lengths
-    br_extent: List[int] = field(init=False)
-    # per-CREATOR evicted counts: the gossip vector clock stays absolute
-    cr_evicted: List[int] = field(init=False)
-
-    def __post_init__(self):
-        n = len(self.participants)
+    def _init_layout(self, n: int) -> None:
+        """An empty layout of ``n`` creators' ``k`` columns each."""
         b = n * self.k
+        # per slot: level, parent slots (-1 a root or evicted), column
+        self.levels: List[int] = []
+        self.sp_slot: List[int] = []
+        self.op_slot: List[int] = []
+        self.ebr: List[int] = []
+        # rolling-window seeds (ForkBatch docstring): ABSOLUTE round and
+        # witness trilean per slot, -1 until the pipeline computes them;
+        # r_off = absolute round of window row 0
+        self.rseed: List[int] = []
+        self.wseed: List[int] = []
+        self.r_off = 0
+        # effective (clamp-enforced) timestamp per slot — same adversarial-ts
+        # defense as HostDag.eff_ts (core/dag.py TS_CLAMP_WINDOW_NS), derived
+        # at insert from the parents' effective values.  The median kernels
+        # consume these, never the signed claims; a fork's branches clamp
+        # against their own ancestry, so equivocating AND lying about time
+        # buys a byzantine creator nothing extra.
+        self.eff_ts: List[int] = []
+        # per branch column: creator, parent branch col (-1), divergence
+        # index, the slots of OWNED events (the segment past the
+        # divergence), the tip slot, and the absolute chain extent (max
+        # index + 1, which survives eviction, unlike window lengths)
         self.br_creator = [c for c in range(n) for _ in range(self.k)]
         self.br_parent = [-1] * b
         self.br_div = [0] * b
-        self.br_events = [[] for _ in range(b)]
+        self.br_events: List[List[int]] = [[] for _ in range(b)]
         self.br_used = [False] * b
-        self.cr_events = [[] for _ in range(n)]
+        self._chain_tip: Dict[int, int] = {}
         self.br_extent = [0] * b
+        # per-CREATOR slots in insertion order (the gossip Known/diff
+        # view) and evicted counts (the gossip vector clock stays absolute)
+        self.cr_events: List[List[int]] = [[] for _ in range(n)]
         self.cr_evicted = [0] * n
 
-    @property
-    def n(self) -> int:
-        return len(self.participants)
-
-    @property
-    def b(self) -> int:
-        return self.n * self.k
-
-    def insert(self, event: Event) -> int:
-        x = event.hex()
-        if x in self.slot_of:
-            raise ValueError("duplicate event")
-        cid = self.participants[event.creator]
-        sp, op = event.self_parent, event.other_parent
-        slot = len(self.events)
-        if sp == "" and op == "":
-            if event.index != 0:
-                raise ValueError("root must have index 0")
-            sps = ops = -1
+    def _claim_column(self, cid: int, sps: int, index: int) -> int:
+        """The branch column of a new event of creator ``cid`` with
+        self-parent slot ``sps`` (-1 for a root): its parent's column if
+        the parent is that column's tip, else a fresh column of the
+        creator's (a fork), within the K-1 fork budget."""
+        if sps < 0:
             col = cid * self.k
             if self.br_used[col]:
                 raise ValueError("duplicate root (index-0 fork unsupported)")
             self.br_used[col] = True
-        else:
-            sps = self.slot_of.get(sp, -1)
-            ops = self.slot_of.get(op, -1)
-            if sps < 0 or ops < 0:
-                raise ParentUnknownError("parent not known")
-            spe = self.events[sps]
-            if spe.creator != event.creator:
-                raise ValueError("self-parent has different creator")
-            if event.index != spe.index + 1:
-                raise ValueError("bad index")
-            pcol = self.ebr[sps]
-            if self._chain_tip.get(pcol) == sps:
-                col = pcol                      # extends the branch tip
-            else:
-                # fork: claim a fresh branch slot of this creator
-                col = -1
-                for kk in range(self.k):
-                    cand = cid * self.k + kk
-                    if not self.br_used[cand]:
-                        col = cand
-                        break
-                if col < 0:
-                    raise ForkBudgetError(
-                        f"creator {cid} exceeded {self.k - 1} forks"
-                    )
+            return col
+        pcol = self.ebr[sps]
+        if self._chain_tip.get(pcol) == sps:
+            return pcol                         # extends the branch tip
+        for kk in range(self.k):
+            col = cid * self.k + kk
+            if not self.br_used[col]:
                 self.br_used[col] = True
                 self.br_parent[col] = pcol
-                self.br_div[col] = event.index
-        self.events.append(event)
-        self.slot_of[x] = slot
-        event.topological_index = self.evicted + slot
+                self.br_div[col] = index
+                return col
+        raise ForkBudgetError(f"creator {cid} exceeded {self.k - 1} forks")
+
+    def _place(self, cid: int, sps: int, ops: int, index: int,
+               claimed_ts: int) -> int:
+        """Record a new event (its parents known, or -1 past the window)
+        in the next slot; returns the slot."""
+        col = self._claim_column(cid, sps, index)
+        slot = len(self.ebr)
         self.cr_events[cid].append(slot)
         self.sp_slot.append(sps)
         self.op_slot.append(ops)
         self.ebr.append(col)
         self.br_events[col].append(slot)
         self._chain_tip[col] = slot
-        self.br_extent[col] = max(self.br_extent[col], event.index + 1)
+        self.br_extent[col] = max(self.br_extent[col], index + 1)
         self.rseed.append(-1)
         self.wseed.append(-1)
         # per-creator eff-ts clamp (engine-parity: timestamp-clamp) —
@@ -268,7 +233,7 @@ class ForkDag:
             op_eff = self.eff_ts[ops]
             parent_ref = op_eff if parent_ref is None \
                 else max(parent_ref, op_eff)
-        self.eff_ts.append(clamp_eff_ts(event.body.timestamp, parent_ref))
+        self.eff_ts.append(clamp_eff_ts(claimed_ts, parent_ref))
         lvl = 0
         if sps >= 0 or ops >= 0:
             lvl = 1 + max(
@@ -278,51 +243,6 @@ class ForkDag:
         self.levels.append(lvl)
         return slot
 
-    # ------------------------------------------------------------------
-
-    def evict_prefix(self, k: int, new_r_off: int) -> None:
-        """Drop the first k slots (a committed prefix the engine proved
-        safe — fork_engine.maybe_compact) and rebase slot references.
-        Slot order is insertion order and chain positions ascend with
-        slot, so a slot prefix is a chain prefix on every branch; chain
-        INDEX values (eseq, cp, la/fd units) are absolute and survive
-        unchanged.  Evicted parents become -1: the pipeline treats such
-        events as pseudo-roots whose round/witness come from rseed/wseed
-        instead of the root rule."""
-        if k <= 0:
-            self.r_off = new_r_off
-            return
-        for s in range(k):
-            del self.slot_of[self.events[s].hex()]
-        self.events = self.events[k:]
-        self.levels = self.levels[k:]
-        self.rseed = self.rseed[k:]
-        self.wseed = self.wseed[k:]
-        self.eff_ts = self.eff_ts[k:]
-
-        def remap(v: int) -> int:
-            return v - k if v >= k else -1
-
-        self.sp_slot = [remap(v) for v in self.sp_slot[k:]]
-        self.op_slot = [remap(v) for v in self.op_slot[k:]]
-        self.ebr = self.ebr[k:]
-        for h in list(self.slot_of):
-            self.slot_of[h] -= k
-        self.br_events = [
-            [s - k for s in lst if s >= k] for lst in self.br_events
-        ]
-        for cid, lst in enumerate(self.cr_events):
-            kept = [s - k for s in lst if s >= k]
-            self.cr_evicted[cid] += len(lst) - len(kept)
-            self.cr_events[cid] = kept
-        self._chain_tip = {
-            col: s - k for col, s in self._chain_tip.items() if s >= k
-        }
-        self.evicted += k
-        self.r_off = new_r_off
-
-    # ------------------------------------------------------------------
-
     def _chain_slots(self, col: int) -> List[int]:
         """Full root->tip slot list of branch col (inherited prefix +
         owned segment)."""
@@ -331,7 +251,7 @@ class ForkDag:
         while c >= 0:
             seg = self.br_events[c]
             if upto is not None:
-                seg = [s for s in seg if self.events[s].index < upto]
+                seg = [s for s in seg if self._index(s) < upto]
             segs.append(seg)
             upto = self.br_div[c]
             c = self.br_parent[c]
@@ -386,34 +306,164 @@ class ForkDag:
                 cp[b1, b2] = min(d1, d2)
         return cp
 
-    def build_batch(self, cfg: ForkConfig) -> ForkBatch:
+    def _fork_batch(self, cfg: ForkConfig, eseq: np.ndarray,
+                    ecr: np.ndarray, mbit: np.ndarray,
+                    sched: np.ndarray) -> ForkBatch:
+        """The ForkBatch of the slots placed so far: ``eseq``, ``ecr``
+        and ``mbit`` per slot, ``sched`` the level schedule."""
         e1 = cfg.e_cap + 1
-        ne = len(self.events)
-        assert ne <= cfg.e_cap, "e_cap too small"
+        ne = len(self.ebr)
+        if ne > cfg.e_cap:
+            raise ValueError(f"e_cap {cfg.e_cap} < {ne} events")
         B, s1 = cfg.b, cfg.s_cap + 1
 
-        sp = np.full(e1, -1, np.int32)
-        op = np.full(e1, -1, np.int32)
-        ebr = np.full(e1, B, np.int32)
-        eseq = np.full(e1, -1, np.int32)
-        ecr = np.full(e1, cfg.n, np.int32)
-        ts = np.zeros(e1, np.int64)
-        mbit = np.zeros(e1, bool)
-        for s, ev in enumerate(self.events):
-            sp[s] = self.sp_slot[s]
-            op[s] = self.op_slot[s]
-            ebr[s] = self.ebr[s]
-            eseq[s] = ev.index
-            ecr[s] = self.participants[ev.creator]
+        def pad1(a, fill, dtype):
+            out = np.full(e1, fill, dtype)
+            out[:ne] = a
+            return out
+
+        ce = np.full((B, s1), -1, np.int32)
+        owner = np.zeros((B, s1), bool)
+        cnt = np.zeros(B, np.int32)
+        s_off = np.zeros(B, np.int32)
+        ebr = np.asarray(self.ebr, np.int32)
+        for col in range(B):
+            if not self.br_used[col]:
+                continue
+            chain = self._chain_slots(col)
+            if len(chain) > cfg.s_cap:
+                raise ValueError(f"s_cap {cfg.s_cap} < chain {len(chain)}")
+            ce[col, : len(chain)] = chain
+            cnt[col] = len(chain)
+            # window positions map to absolute chain indexes by a per-
+            # branch offset (contiguous: prefix eviction drops a chain
+            # prefix, and chain indexes step by one)
+            s_off[col] = self._index(chain[0]) if chain else 0
+            owner[col, : len(chain)] = ebr[chain] == col
+
+        rseed = np.asarray(self.rseed, np.int64)
+        seeded = rseed >= 0
+        return ForkBatch(
+            sp=jnp.asarray(pad1(self.sp_slot, -1, np.int32)),
+            op=jnp.asarray(pad1(self.op_slot, -1, np.int32)),
+            ebr=jnp.asarray(pad1(ebr, B, np.int32)),
+            eseq=jnp.asarray(pad1(eseq, -1, np.int32)),
+            ecr=jnp.asarray(pad1(ecr, cfg.n, np.int32)),
             # effective (clamped) timestamps, never the signed claims —
             # the adversarial-ts defense's single seam, like dag.eff_ts
-            ts[s] = self.eff_ts[s]
-            mbit[s] = ev.middle_bit()
+            ts=jnp.asarray(pad1(self.eff_ts, 0, np.int64)),
+            mbit=jnp.asarray(pad1(mbit, False, bool)),
+            sched=jnp.asarray(sched), cp=jnp.asarray(self.common_prefix()),
+            ce=jnp.asarray(ce), cnt=jnp.asarray(cnt),
+            owner=jnp.asarray(owner), n_events=jnp.asarray(ne, jnp.int32),
+            rseed=jnp.asarray(pad1(
+                np.where(seeded, rseed - self.r_off, -1), -1, np.int32)),
+            wseed=jnp.asarray(pad1(
+                np.where(seeded, self.wseed, -1), -1, np.int8)),
+            s_off=jnp.asarray(s_off),
+        )
 
+
+@dataclass
+class ForkDag(_BranchLayout):
+    """Host index for byzantine mode: assigns branch columns, builds the
+    chain views + common-prefix matrix the kernels need."""
+
+    participants: Dict[str, int]
+    k: int = 2
+
+    events: List[Event] = field(default_factory=list)
+    slot_of: Dict[str, int] = field(default_factory=dict)
+    evicted: int = 0                  # slots dropped by evict_prefix, total
+
+    def __post_init__(self):
+        self._init_layout(len(self.participants))
+
+    @property
+    def n(self) -> int:
+        return len(self.participants)
+
+    @property
+    def b(self) -> int:
+        return self.n * self.k
+
+    def _index(self, slot: int) -> int:
+        return self.events[slot].index
+
+    def insert(self, event: Event) -> int:
+        x = event.hex()
+        if x in self.slot_of:
+            raise ValueError("duplicate event")
+        cid = self.participants[event.creator]
+        sp, op = event.self_parent, event.other_parent
+        if sp == "" and op == "":
+            if event.index != 0:
+                raise ValueError("root must have index 0")
+            sps = ops = -1
+        else:
+            sps = self.slot_of.get(sp, -1)
+            ops = self.slot_of.get(op, -1)
+            if sps < 0 or ops < 0:
+                raise ParentUnknownError("parent not known")
+            spe = self.events[sps]
+            if spe.creator != event.creator:
+                raise ValueError("self-parent has different creator")
+            if event.index != spe.index + 1:
+                raise ValueError("bad index")
+        slot = self._place(cid, sps, ops, event.index, event.body.timestamp)
+        self.events.append(event)
+        self.slot_of[x] = slot
+        event.topological_index = self.evicted + slot
+        return slot
+
+    # ------------------------------------------------------------------
+
+    def evict_prefix(self, k: int, new_r_off: int) -> None:
+        """Drop the first k slots (a committed prefix the engine proved
+        safe — fork_engine.maybe_compact) and rebase slot references.
+        Slot order is insertion order and chain positions ascend with
+        slot, so a slot prefix is a chain prefix on every branch; chain
+        INDEX values (eseq, cp, la/fd units) are absolute and survive
+        unchanged.  Evicted parents become -1: the pipeline treats such
+        events as pseudo-roots whose round/witness come from rseed/wseed
+        instead of the root rule."""
+        if k <= 0:
+            self.r_off = new_r_off
+            return
+        for s in range(k):
+            del self.slot_of[self.events[s].hex()]
+        self.events = self.events[k:]
+        self.levels = self.levels[k:]
+        self.rseed = self.rseed[k:]
+        self.wseed = self.wseed[k:]
+        self.eff_ts = self.eff_ts[k:]
+
+        def remap(v: int) -> int:
+            return v - k if v >= k else -1
+
+        self.sp_slot = [remap(v) for v in self.sp_slot[k:]]
+        self.op_slot = [remap(v) for v in self.op_slot[k:]]
+        self.ebr = self.ebr[k:]
+        for h in list(self.slot_of):
+            self.slot_of[h] -= k
+        self.br_events = [
+            [s - k for s in lst if s >= k] for lst in self.br_events
+        ]
+        for cid, lst in enumerate(self.cr_events):
+            kept = [s - k for s in lst if s >= k]
+            self.cr_evicted[cid] += len(lst) - len(kept)
+            self.cr_events[cid] = kept
+        self._chain_tip = {
+            col: s - k for col, s in self._chain_tip.items() if s >= k
+        }
+        self.evicted += k
+        self.r_off = new_r_off
+
+    def build_batch(self, cfg: ForkConfig) -> ForkBatch:
         lev = np.asarray(self.levels, np.int64)
         order = np.argsort(lev, kind="stable")
         ulev, starts = np.unique(lev[order], return_index=True)
-        bounds = list(starts) + [ne]
+        bounds = list(starts) + [len(self.events)]
         # bucket the schedule dims to powers of two (state.bucket):
         # exact (levels, widest-level) shapes change almost every
         # consensus tick, and each distinct shape is a full pipeline
@@ -431,42 +481,54 @@ class ForkDag:
         for row in range(len(ulev)):
             grp = order[bounds[row] : bounds[row + 1]]
             sched[row, : len(grp)] = grp
-
-        ce = np.full((B, s1), -1, np.int32)
-        owner = np.zeros((B, s1), bool)
-        cnt = np.zeros(B, np.int32)
-        s_off = np.zeros(B, np.int32)
-        for col in range(B):
-            if not self.br_used[col]:
-                continue
-            chain = self._chain_slots(col)
-            assert len(chain) <= cfg.s_cap, "s_cap too small"
-            ce[col, : len(chain)] = chain
-            cnt[col] = len(chain)
-            # window positions map to absolute chain indexes by a per-
-            # branch offset (contiguous: prefix eviction drops a chain
-            # prefix, and chain indexes step by one)
-            s_off[col] = self.events[chain[0]].index if chain else 0
-            for i, s in enumerate(chain):
-                owner[col, i] = self.ebr[s] == col
-
-        rseed = np.full(e1, -1, np.int32)
-        wseed = np.full(e1, -1, np.int8)
-        if self.rseed is not None:
-            for s in range(ne):
-                if self.rseed[s] >= 0:
-                    rseed[s] = self.rseed[s] - self.r_off
-                    wseed[s] = self.wseed[s]
-        return ForkBatch(
-            sp=jnp.asarray(sp), op=jnp.asarray(op), ebr=jnp.asarray(ebr),
-            eseq=jnp.asarray(eseq), ecr=jnp.asarray(ecr),
-            ts=jnp.asarray(ts), mbit=jnp.asarray(mbit),
-            sched=jnp.asarray(sched), cp=jnp.asarray(self.common_prefix()),
-            ce=jnp.asarray(ce), cnt=jnp.asarray(cnt),
-            owner=jnp.asarray(owner), n_events=jnp.asarray(ne, jnp.int32),
-            rseed=jnp.asarray(rseed), wseed=jnp.asarray(wseed),
-            s_off=jnp.asarray(s_off),
+        evs = self.events
+        return self._fork_batch(
+            cfg,
+            eseq=np.asarray([ev.index for ev in evs], np.int32),
+            ecr=np.asarray([self.participants[ev.creator] for ev in evs],
+                           np.int32),
+            mbit=np.asarray([ev.middle_bit() for ev in evs], bool),
+            sched=sched,
         )
+
+
+class ForkArrays(_BranchLayout):
+    """A recorded DAG's plain arrays (slot order topological; ``sp`` /
+    ``op`` parent slots, -1 for roots) under ForkDag's branch rules —
+    the batch path's twin of inserting every event into a ForkDag."""
+
+    def __init__(self, n: int, k: int, sp, op, creator, seq, ts):
+        self.k = k
+        self.b = n * k
+        self._seq = np.asarray(seq, np.int32)
+        sp, op = np.asarray(sp), np.asarray(op)
+        creator = self._creator = np.asarray(creator, np.int32)
+        slots = np.arange(len(sp))
+        if np.any((creator < 0) | (creator >= n)):
+            raise ValueError(f"a creator outside 0..{n - 1}")
+        root = sp < 0
+        if np.any(root != (op < 0)) or np.any(self._seq[root] != 0):
+            raise ValueError("a root must have index 0 and no parents")
+        if np.any((sp >= slots) | (op >= slots)):
+            raise ValueError("slots are not in topological order")
+        spx = np.where(root, 0, sp)
+        if np.any(~root & ((creator[spx] != creator)
+                           | (self._seq[spx] + 1 != self._seq))):
+            raise ValueError("a self-parent of another creator or index")
+        self._init_layout(n)
+        for c, p, q, i, t in zip(creator.tolist(), sp.tolist(), op.tolist(),
+                                 self._seq.tolist(), np.asarray(ts).tolist()):
+            self._place(c, p, q, i, t)
+
+    def _index(self, slot: int) -> int:
+        return int(self._seq[slot])
+
+    def build_batch(self, cfg: ForkConfig, mbit,
+                    sched: np.ndarray) -> ForkBatch:
+        """The ForkBatch of every event, ``mbit`` its coin bits and
+        ``sched`` the level schedule."""
+        return self._fork_batch(cfg, self._seq, self._creator,
+                                np.asarray(mbit, bool), sched)
 
 
 # ----------------------------------------------------------------------
@@ -729,7 +791,7 @@ def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
     ].max(jnp.where(w_chain, b.ce, -1))
 
     def round_step(carry):
-        r, rnd, unassigned, wslot, alive = carry
+        r, rnd, unassigned, wslot, alive, steps = carry
         # candidate frontier: first chain position with round >= r
         # (rounds are monotone along chains; seeded prefixes count too)
         rnd_chain = jnp.where(live_chain, rnd[cex], -1)
@@ -755,13 +817,13 @@ def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
 
         # descent closure of S within the unassigned set
         def cl_body(c):
-            D, _ = c
+            D, _, it = c
             D2 = S | (unassigned & (D[spx] | D[opx]))
             D2 = D2 & valid_e
-            return D2, (D2 != D).any()
+            return D2, (D2 != D).any(), it + 1
 
-        D, _ = jax.lax.while_loop(
-            lambda c: c[1], cl_body, (S, jnp.asarray(True))
+        D, _, it = jax.lax.while_loop(
+            lambda c: c[1], cl_body, (S, jnp.asarray(True), steps)
         )
 
         newly = unassigned & ~D
@@ -776,26 +838,26 @@ def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
         wslot = wslot.at[row].set(jnp.where(is_w, ws, wslot[row]))
 
         alive = D.any()
-        return r + 1, rnd, D, wslot, alive
+        return r + 1, rnd, D, wslot, alive, it
 
     def cond(carry):
-        r, _, _, _, alive = carry
+        r, _, _, _, alive, _ = carry
         # rounds 0..r_cap-1 are assignable (wslot rows 0..r_cap-1, same
         # as the level scan); `r < r_cap - 1` here was an off-by-one that
         # silently dropped the top round at tight capacities
         return alive & (r < r_cap)
 
     unassigned0 = valid_e & ~seeded
-    _, rnd, _, wslot, _ = jax.lax.while_loop(
+    _, rnd, _, wslot, _, closure_steps = jax.lax.while_loop(
         cond, round_step,
         (jnp.asarray(0, I32), rnd0, unassigned0, wslot0,
-         jnp.asarray(True)),
+         jnp.asarray(True), jnp.asarray(0, I32)),
     )
 
     wit = valid_e & ((b.sp < 0) | (rnd > rnd[spx]))
     wit = jnp.where(b.wseed >= 0, b.wseed == 1, wit) & valid_e
     max_round = jnp.max(jnp.where(valid_e, rnd, -1))
-    return rnd, wit, wslot, max_round
+    return rnd, wit, wslot, max_round, closure_steps
 
 
 def _rounds_scan(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
@@ -916,7 +978,7 @@ def _fame(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray, det: jnp.ndarray,
     in_window = i_idx < max_round
 
     def step(d, carry):
-        votes, famous = carry
+        votes, famous, steps = carry
         d = jnp.asarray(d, I32)
         can_vote = (i_idx + d) <= max_round
         z = jnp.zeros((), I32)
@@ -944,11 +1006,12 @@ def _fame(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray, det: jnp.ndarray,
         coin_vote = jnp.where(strong, v, mb_d[:, :, None])
         new_votes = jnp.where(normal, v, coin_vote).astype(F32)
         votes = jnp.where(can_vote[:, None, None], new_votes, votes)
-        return votes, famous
+        return votes, famous, steps + 1
 
     d_max = jnp.maximum(max_round, 2)
-    votes, famous = jax.lax.fori_loop(
-        2, d_max + 1, step, (see_next, jnp.zeros((R, B), jnp.int8))
+    votes, famous, vote_steps = jax.lax.fori_loop(
+        2, d_max + 1, step,
+        (see_next, jnp.zeros((R, B), jnp.int8), jnp.asarray(0, I32)),
     )
 
     decided_round = ((~valid_w) | (famous != FAME_UNDEFINED)).all(axis=1)
@@ -956,7 +1019,7 @@ def _fame(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray, det: jnp.ndarray,
     cand = in_window & decided_round & has_w
     lcr = jnp.max(jnp.where(cand, i_idx, -1))
     famous_full = jnp.zeros((R + 1, B), jnp.int8).at[:R].set(famous)
-    return famous_full, lcr
+    return famous_full, lcr, vote_steps
 
 
 def _order(cfg: ForkConfig, b: ForkBatch, fd: jnp.ndarray,
@@ -1021,24 +1084,39 @@ def _order(cfg: ForkConfig, b: ForkBatch, fd: jnp.ndarray,
 
 
 def fork_pipeline_impl(cfg: ForkConfig, b: ForkBatch) -> ForkOut:
-    la = _la_scan(cfg, b)
-    det = _detect(cfg, b, la)
-    first_det = _first_det(cfg, b, det)
+    """The whole fork-aware pipeline over one batch.  Its phases run
+    under the fused step's ``named_scope`` names (parallel/sharded.py
+    ``consensus_step_impl``): ``babble_ingest`` with ``la`` / ``fd`` /
+    ``rounds`` children (fork detection and the see-interval helper are
+    ingest's own), ``babble_fame`` and ``babble_order``."""
     # shared measured cost model (state.fd_reverse_scan_wins); the fork
     # chain-view count is k^2 heavier than the honest one it was fit to
     from .state import fd_reverse_scan_wins
 
-    if fd_reverse_scan_wins(b.sched.shape[0], cfg.e_cap, cfg.k):
-        fd = _fd_reverse(cfg, b)
-    else:
-        fd = _fd_chains(cfg, b, la)
-    helper = _helper(cfg, b, fd, first_det)
-    rnd, wit, wslot, max_round = _rounds_closure(cfg, b, la, det, helper)
-    famous, lcr = _fame(cfg, b, la, det, helper, wslot, max_round)
-    rr, cts = _order(cfg, b, fd, first_det, wslot, famous, rnd, max_round)
+    with jax.named_scope("babble_ingest"):
+        with jax.named_scope("la"):
+            la = _la_scan(cfg, b)
+        det = _detect(cfg, b, la)
+        first_det = _first_det(cfg, b, det)
+        with jax.named_scope("fd"):
+            if fd_reverse_scan_wins(b.sched.shape[0], cfg.e_cap, cfg.k):
+                fd = _fd_reverse(cfg, b)
+            else:
+                fd = _fd_chains(cfg, b, la)
+        helper = _helper(cfg, b, fd, first_det)
+        with jax.named_scope("rounds"):
+            rnd, wit, wslot, max_round, closure_steps = _rounds_closure(
+                cfg, b, la, det, helper)
+    with jax.named_scope("babble_fame"):
+        famous, lcr, vote_steps = _fame(cfg, b, la, det, helper, wslot,
+                                        max_round)
+    with jax.named_scope("babble_order"):
+        rr, cts = _order(cfg, b, fd, first_det, wslot, famous, rnd,
+                         max_round)
     return ForkOut(
         la=la, det=det, fd=fd, round=rnd, witness=wit, wslot=wslot,
         famous=famous, rr=rr, cts=cts, max_round=max_round, lcr=lcr,
+        closure_steps=closure_steps, vote_steps=vote_steps,
     )
 
 

@@ -52,6 +52,19 @@ class ArrayDag:
     def max_chain(self) -> int:
         return int(self.seq.max()) + 1 if len(self.seq) else 0
 
+    @property
+    def branch_slots(self) -> int:
+        """Branch columns the DAG needs per creator: the most chain tips
+        (events no other event extends) that any creator has.  Above 1,
+        the DAG holds an equivocation: two events of one creator at one
+        index."""
+        if not self.n_events:
+            return 1
+        extended = np.zeros(self.n_events, bool)
+        extended[self.sp[self.sp >= 0]] = True
+        return int(np.bincount(self.creator[~extended],
+                               minlength=self.n).max())
+
     def participants(self) -> Dict[str, int]:
         """Fake identities compatible with sim.generator's naming."""
         from .generator import _fake_pub
@@ -250,6 +263,34 @@ def batch_from_arrays(dag: ArrayDag, bucket=None):
     )
 
 
+def pad_schedule(sched: np.ndarray, rows: int, width: int) -> np.ndarray:
+    """A level schedule padded with -1 to ``rows`` x ``width``, so that
+    DAGs of equal sizes run one compiled program; ``rows`` 0 leaves it
+    as it is."""
+    if not rows:
+        return sched
+    if sched.shape[0] > rows or sched.shape[1] > width:
+        raise ValueError(f"the schedule {sched.shape} exceeds "
+                         f"{rows} x {width}")
+    out = np.full((rows, width), -1, np.int32)
+    out[:sched.shape[0], :sched.shape[1]] = sched
+    return out
+
+
+def fork_batch_from_arrays(dag: ArrayDag, cfg, sched_rows: int = 0):
+    """ArrayDag -> ops.forks.ForkBatch, its events placed in branch
+    columns by ForkDag's rules (ops.forks.ForkArrays); a ``sched_rows``
+    pads the level schedule to that many rows of ``cfg.b`` (a level
+    holds at most one event per branch column)."""
+    from ..ops.forks import ForkArrays
+
+    lay = ForkArrays(dag.n, cfg.k, dag.sp, dag.op, dag.creator, dag.seq,
+                     dag.ts)
+    sched = build_schedule(np.asarray(lay.levels, np.int32))
+    return lay.build_batch(cfg, dag.mbit,
+                           pad_schedule(sched, sched_rows, cfg.b))
+
+
 def cap_schedule_width(sched: np.ndarray, max_width: int) -> np.ndarray:
     """Split wide schedule rows into several rows of <= max_width entries.
 
@@ -292,19 +333,14 @@ def random_byzantine_fork_batch(
 
     One fork per byzantine creator (branch budget K=2); matches
     sim.generator.random_byzantine_dag's shape with forks_per_node=1."""
-    import jax.numpy as jnp
-
-    from ..ops.forks import ForkBatch, ForkConfig
-    from ..ops.state import INT32_MAX
+    from ..ops.forks import ForkArrays, ForkConfig
 
     rng = np.random.default_rng(seed)
     k = 2
-    b_total = n * k
     n_byz = min(int(byz_frac * n), n - supermajority(n))
 
     sp = np.full(n_events, -1, np.int32)
     op = np.full(n_events, -1, np.int32)
-    ebr = np.zeros(n_events, np.int32)
     eseq = np.zeros(n_events, np.int32)
     ecr = np.zeros(n_events, np.int32)
     ts = np.zeros(n_events, np.int64)
@@ -312,15 +348,12 @@ def random_byzantine_fork_batch(
     levels = np.zeros(n_events, np.int32)
 
     heads = np.full(n, -1, np.int32)          # current head slot per node
-    cur_col = np.arange(n, dtype=np.int32) * k
     cur_idx = np.full(n, -1, np.int32)
     forked = np.zeros(n, bool)
-    fork_div = np.full(n, -1, np.int32)       # divergence index per creator
     own_slots: list = [[] for _ in range(n)]  # all own slots in order
 
     e = 0
     for i in range(min(n, n_events)):
-        ebr[e] = i * k
         ecr[e] = i
         ts[e] = base_ts
         heads[i] = e
@@ -340,20 +373,16 @@ def random_byzantine_fork_batch(
 
         sp_slot = heads[r]
         idx = cur_idx[r] + 1
-        col = cur_col[r]
         if (r < n_byz and not forked[r] and cur_idx[r] >= 1
                 and rng.random() < fork_rate):
             # equivocate once: branch off a random earlier own event
+            # (ForkDag's rules give it, and what extends it, column r*k+1)
             j = int(rng.integers(0, len(own_slots[r]) - 1))
             sp_slot = own_slots[r][j]
             idx = eseq[sp_slot] + 1
-            col = r * k + 1
             forked[r] = True
-            fork_div[r] = idx
-            cur_col[r] = col
         sp[e] = sp_slot
         op[e] = heads[s]
-        ebr[e] = col
         eseq[e] = idx
         ecr[e] = r
         ts[e] = tstamp
@@ -363,7 +392,6 @@ def random_byzantine_fork_batch(
         own_slots[r].append(e)
         e += 1
 
-    # chain views
     max_chain = int(eseq.max()) + 1
     # fame tensors are [R, B, B]: keep r_cap tight (callers size it to the
     # expected round count; the bench asserts post-run headroom)
@@ -375,51 +403,6 @@ def random_byzantine_fork_batch(
             3, (int(levels.max()) // 3 + 4 - 1).bit_length()
         ),
     )
-    e1, s1 = cfg.e_cap + 1, cfg.s_cap + 1
-
-    ce = np.full((b_total, s1), -1, np.int32)
-    owner = np.zeros((b_total, s1), bool)
-    cnt = np.zeros(b_total, np.int32)
-    cp = np.zeros((b_total, b_total), np.int32)
-    np.fill_diagonal(cp, INT32_MAX)
-    for i in range(n):
-        main, alt = i * k, i * k + 1
-        main_slots = [s_ for s_ in own_slots[i] if ebr[s_] == main]
-        ce[main, : len(main_slots)] = main_slots
-        owner[main, : len(main_slots)] = True
-        cnt[main] = len(main_slots)
-        if forked[i]:
-            d = int(fork_div[i])
-            alt_slots = [s_ for s_ in own_slots[i] if ebr[s_] == alt]
-            chain = main_slots[:d] + alt_slots
-            ce[alt, : len(chain)] = chain
-            owner[alt, d : len(chain)] = True
-            cnt[alt] = len(chain)
-            cp[main, alt] = cp[alt, main] = d
-
+    lay = ForkArrays(n, k, sp, op, ecr, eseq, ts)
     sched = cap_schedule_width(build_schedule(levels), sched_width)
-
-    def pad1(a, fill):
-        out = np.full(e1, fill, a.dtype)
-        out[:n_events] = a
-        return out
-
-    batch = ForkBatch(
-        sp=jnp.asarray(pad1(sp, -1)),
-        op=jnp.asarray(pad1(op, -1)),
-        ebr=jnp.asarray(pad1(ebr, b_total)),
-        eseq=jnp.asarray(pad1(eseq, -1)),
-        ecr=jnp.asarray(pad1(ecr, n)),
-        ts=jnp.asarray(pad1(ts, 0)),
-        mbit=jnp.asarray(pad1(mbit, False)),
-        sched=jnp.asarray(sched),
-        cp=jnp.asarray(cp),
-        ce=jnp.asarray(ce),
-        cnt=jnp.asarray(cnt),
-        owner=jnp.asarray(owner),
-        n_events=jnp.asarray(n_events, np.int32),
-        rseed=jnp.full(e1, -1, np.int32),
-        wseed=jnp.full(e1, -1, np.int8),
-        s_off=jnp.zeros(b_total, np.int32),
-    )
-    return cfg, batch
+    return cfg, lay.build_batch(cfg, mbit, sched)

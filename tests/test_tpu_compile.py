@@ -143,6 +143,33 @@ def test_fused_step_carries_its_phase_scopes(one_chip, chip_compile):
         assert any(path in n for n in names), path
 
 
+def test_fork_pipeline_carries_its_phase_scopes(one_chip, chip_compile):
+    """The sim step on a 4 x 4,096 DAG with one equivocation (the fork
+    pipeline) compiled for the chip keeps the fused step's six scope
+    paths: ``babble_ingest`` own work (fork detection) and its ``la`` /
+    ``fd`` / ``rounds`` children, ``babble_fame``, ``babble_order``."""
+    import re
+
+    from babble_tpu.cli import sim_inputs, sim_step
+    from babble_tpu.ops.forks import ForkConfig
+    from babble_tpu.sim.arrays import ArrayDag
+    from benchmark.reference import fork_native
+
+    dag = fork_native.fork_dag(4, 4096, 7, 1)
+    adag = ArrayDag(4, *(dag[k] for k in ("sp", "op", "creator", "seq",
+                                          "ts", "mbit", "levels")), 7)
+    cfg, step = sim_step(adag, 512)
+    assert isinstance(cfg, ForkConfig)
+    text = step.lower(
+        *_abstract(sim_inputs(adag, cfg, 2560), one_chip)).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for path in ("babble_ingest/la/", "babble_ingest/fd/",
+                 "babble_ingest/rounds/", "babble_fame/", "babble_order/"):
+        assert any(path in n for n in names), path
+    assert any(re.search(r"babble_ingest/(?!la/|fd/|rounds/)", n)
+               for n in names), "babble_ingest's own work"
+
+
 def test_wide_onehot_strongly_see_compiles_at_10k(one_chip, chip_compile):
     """The wide engine's per-block strongly-see partial at n = 10,000
     takes the int8 one-hot matmul on the chip, and compiles there."""
