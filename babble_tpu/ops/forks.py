@@ -124,6 +124,7 @@ class ForkOut(NamedTuple):
     lcr: jnp.ndarray
     closure_steps: jnp.ndarray  # i32: descent-closure iterations, all rounds
     vote_steps: jnp.ndarray     # i32: diagonal vote steps fame ran
+    band_fallbacks: jnp.ndarray  # i32: rounds the closure ran full-width
 
 
 # ----------------------------------------------------------------------
@@ -729,26 +730,37 @@ def _ss_counts(cfg: ForkConfig, la_x: jnp.ndarray, det_x: jnp.ndarray,
     return (ok & ~det_x).sum(-1, dtype=I32)
 
 
+#: Chain positions above each branch's frontier that one round of
+#: ``_rounds_closure`` examines.  A round assigns the positions
+#: ``[pos, t)`` of every branch; on the 4 x 65,536 benchmark DAGs one
+#: creator mints 4.2 events a round on average and at most 34 (the fork
+#: reference over 1,000 seeds, PERF.md section 3), so 64 leaves room.  A
+#: round whose band runs out falls back to the full pass over the event
+#: axis, exact at any width.
+ROUND_BAND = 64
+
+
 def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
-                    det: jnp.ndarray, helper: jnp.ndarray):
+                    det: jnp.ndarray, helper: jnp.ndarray,
+                    band: int = ROUND_BAND):
     """Round assignment as a per-round closure iteration — the fork-aware
     analogue of the honest frontier march (ingest.py _rounds_frontier),
     replacing the level scan whose per-step witness gathers were ~90% of
     byzantine wall time (VERDICT r2 weak #3: 3,315 sequential steps,
     each gathering a [32, B, B] helper tensor).
 
-    Per round r (at most max_round+1 iterations, each one fused program
-    over the whole event axis):
+    Per round r (at most max_round+1 iterations):
 
     - candidate witnesses = each branch's first not-yet-assigned event
-      (the chain frontier).  Some candidates' true rounds exceed r
-      ("jumps" via the other parent); they are harmless in the
+      (the chain frontier ``pos``).  Some candidates' true rounds exceed
+      r ("jumps" via the other parent); they are harmless in the
       supermajority count by the same ancestry-composition argument as
       the honest march: strongly-seeing a jumped candidate implies
       descending from it, and descent alone already lifts the seer past
       round r (rounds are monotone along parent edges).
     - S = unassigned events that strongly see >= 2n/3+1 candidate
-      CREATORS (the fork-aware count: branch-OR, detection-masked).
+      CREATORS (the fork-aware count: branch-OR, detection-masked), or
+      whose parent's (seeded) round exceeds r.
     - round > r iff in the descent closure of S: D = S | D[sp] | D[op],
       iterated to fixpoint (rounds inherit through parents even when
       later fork detection would discount the middlemen — which is why
@@ -756,17 +768,36 @@ def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
       detection-masked count is not monotone along a chain).
     - everything unassigned outside D has round exactly r.
 
-    Assigned rounds form a prefix of every chain (round is monotone
-    along chains), so the frontier is just the per-branch assigned
-    count.  Witness tables come from the frontier: branch b's round-r
-    witness is its frontier event iff that event was assigned round r
-    and b owns the position (shared fork prefixes belong to one branch
-    column only).  Bit-parity with the byzantine oracle is pinned by
+    Assigned rounds form a prefix of every chain and "round > r" (D plus
+    the seeded events above r) a suffix, so one threshold per branch,
+    ``t[b]``, describes D, and round r is exactly the positions
+    ``[pos[b], t[b])``.  A round therefore works on a band of ``band``
+    positions above each frontier, not on the event axis: S is counted
+    for the band's events only, and the closure is a fixpoint over t.
+    ``t[b]`` is the first band position whose event strongly sees a
+    supermajority or is seeded above r, or whose other parent lies at or
+    above its own column's threshold (which covers a parent seeded above
+    r).  A parent's status is read from its owner column's t, so a
+    common-prefix position, one event in several views, has one status;
+    a self-parent is the previous position of the same view, so its
+    status is already the view's own threshold.  Started from S and
+    moving only down, t never marks an event that D lacks, and it
+    reaches D unless a branch still has positions past its band and met
+    no round > r inside it.  That round runs the full pass over the
+    event axis instead, exact at any width, and counts in
+    ``band_fallbacks``.  ``closure_steps`` sums the closure iterations
+    of the pass that decided each round.
+
+    Witness tables come from the frontier: branch b's round-r witness is
+    its frontier event iff that event was assigned round r and b owns
+    the position (shared fork prefixes belong to one branch column
+    only).  Bit-parity with the byzantine oracle is pinned by
     tests/test_forks.py."""
     n, k, B, sm, r_cap = cfg.n, cfg.k, cfg.b, cfg.super_majority, cfg.r_cap
     e1 = cfg.e_cap + 1
     s_cap = cfg.s_cap
-    rows = jnp.arange(B)
+    W = min(band, s_cap + 1)
+    rows = jnp.arange(B, dtype=I32)
 
     valid_e = (jnp.arange(e1) < b.n_events) & (b.eseq >= 0)
     spx = sanitize(b.sp, cfg.e_cap)
@@ -790,30 +821,37 @@ def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
         jnp.clip(w_round, 0, r_cap), rows[:, None].repeat(s_cap + 1, 1)
     ].max(jnp.where(w_chain, b.ce, -1))
 
-    def round_step(carry):
-        r, rnd, unassigned, wslot, alive, steps = carry
-        # candidate frontier: first chain position with round >= r
-        # (rounds are monotone along chains; seeded prefixes count too)
+    def ss_ok(la_x, det_x, hw, valid_w):
+        # x strongly sees >= sm candidate creators (branch-OR)
+        ss_cnt = _ss_counts(cfg, la_x[..., None, :], det_x[..., None, :],
+                            hw)                               # [..., B]
+        ss = (ss_cnt >= sm) & valid_w
+        ss_c = ss[..., 0::k]
+        for kk in range(1, k):
+            ss_c = ss_c | ss[..., kk::k]
+        return ss_c.sum(-1) >= sm
+
+    def witness_row(wslot, r, ws, is_w):
+        # witness table row r: the frontier event, when it was assigned
+        # round r and the branch owns the position (keep seeded entries
+        # of other branches in the row)
+        row = jnp.minimum(r, r_cap)
+        return wslot.at[row].set(jnp.where(is_w, ws, wslot[row]))
+
+    def full_round(r, rnd, wslot, steps):
+        # the whole event axis: exact whatever the band saw
         rnd_chain = jnp.where(live_chain, rnd[cex], -1)
         pos = ((rnd_chain >= 0) & (rnd_chain < r)).sum(-1, dtype=I32)
         valid_w = pos < b.cnt
         ws = b.ce[rows, jnp.clip(pos, 0, s_cap)]
         wsx = sanitize(jnp.where(valid_w, ws, -1), cfg.e_cap)
         hw = jnp.where(valid_w[:, None], helper[wsx], INT32_MAX)  # [B, B]
-
-        # S: unassigned events strongly seeing >= sm candidate creators
-        ss_cnt = _ss_counts(
-            cfg, la[:, None, :], det[:, None, :], hw[None, :, :]
-        )                                                     # [E+1, B]
-        ss = (ss_cnt >= sm) & valid_w[None, :]
-        ss_c = ss[..., 0::k]
-        for kk in range(1, k):
-            ss_c = ss_c | ss[..., kk::k]
+        unassigned = valid_e & (rnd < 0)
         # parent rounds above r also lift (rounds are monotone through
         # parent edges) — this is what lets seeded boundaries skip the
         # rounds the window no longer has full ancestry for
         pr_gt = jnp.maximum(rnd[spx], rnd[opx]) > r
-        S = unassigned & ((ss_c.sum(-1) >= sm) | pr_gt)
+        S = unassigned & (ss_ok(la, det, hw, valid_w) | pr_gt)
 
         # descent closure of S within the unassigned set
         def cl_body(c):
@@ -825,39 +863,99 @@ def _rounds_closure(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
         D, _, it = jax.lax.while_loop(
             lambda c: c[1], cl_body, (S, jnp.asarray(True), steps)
         )
-
         newly = unassigned & ~D
         rnd = jnp.where(newly, r, rnd)
-
-        # witness table row r: the frontier event, when it was assigned
-        # round r and the branch owns the position (keep seeded entries
-        # of other branches in the row)
         owner_w = b.owner[rows, jnp.clip(pos, 0, s_cap)]
-        is_w = valid_w & newly[wsx] & owner_w
-        row = jnp.minimum(r, r_cap)
-        wslot = wslot.at[row].set(jnp.where(is_w, ws, wslot[row]))
+        wslot = witness_row(wslot, r, ws, valid_w & newly[wsx] & owner_w)
+        rnd_chain = jnp.where(live_chain, rnd[cex], -1)
+        pos = ((rnd_chain >= 0) & (rnd_chain <= r)).sum(-1, dtype=I32)
+        return rnd, pos, wslot, D.sum(dtype=I32), it
 
-        alive = D.any()
-        return r + 1, rnd, D, wslot, alive, it
+    # each event's other parent as (owner column, window position on
+    # it); an evicted or absent parent reads column B, whose threshold
+    # is +inf
+    op_col = b.ebr[opx]
+    op_q = b.eseq[opx] - b.s_off[jnp.clip(op_col, 0, B - 1)]
+    cols = jnp.arange(B + 1, dtype=I32)
+    # a column owns a suffix of its chain view (the prefix it shares
+    # with the branch it forked from is that branch's); read so, the
+    # loop leaves b.ebr alone, and the compiler keeps it in the chip's
+    # fast memory for the la scan
+    own_lo = b.cnt - b.owner.sum(-1, dtype=I32)
+
+    def round_step(carry):
+        r, rnd, pos, wslot, n_un, steps, fallbacks = carry
+        # the band: positions pos .. pos + W - 1 of every branch, and its
+        # first position's event the branch's candidate witness
+        bpos = pos[:, None] + jnp.arange(W, dtype=I32)         # [B, W]
+        live = bpos < b.cnt[:, None]
+        bx = jnp.where(live, b.ce[rows[:, None], jnp.clip(bpos, 0, s_cap)],
+                       cfg.e_cap)
+        valid_w, ws = live[:, 0], bx[:, 0]
+        hw = jnp.where(valid_w[:, None], helper[ws], INT32_MAX)  # [B, B]
+        rb = rnd[bx]
+        un = live & (rb < 0)
+        # strongly seeing a supermajority, or seeded above r; a parent
+        # above r lifts through the thresholds below
+        hi0 = (un & ss_ok(la[bx], det[bx], hw, valid_w)) | (live & (rb > r))
+        b_ocol, b_oq = op_col[bx], op_q[bx]
+
+        def first_hi(hi):
+            return jnp.min(jnp.where(hi, bpos, b.cnt[:, None]), axis=1)
+
+        def cl_body(c):
+            t, _, it = c
+            # t at the other parent's column, as a select: a gather of so
+            # small an operand is a kernel of its own on the chip
+            t_col = jnp.concatenate([t, jnp.full((1,), INT32_MAX, I32)])
+            t_op = jnp.max(jnp.where(b_ocol[..., None] == cols, t_col, -1),
+                           axis=-1)
+            t2 = first_hi(hi0 | (un & (b_oq >= t_op)))
+            return t2, (t2 != t).any(), it + 1
+
+        t, _, it = jax.lax.while_loop(
+            lambda c: c[1], cl_body, (first_hi(hi0), jnp.asarray(True),
+                                      steps)
+        )
+        # a branch with positions past its band and no round > r in it
+        ran_out = ((t == b.cnt) & (pos + W < b.cnt)).any()
+
+        def band_round(_):
+            newly = un & (bpos < t[:, None])
+            owned = newly & (bpos >= own_lo[:, None])
+            rnd2 = rnd.at[jnp.where(newly, bx, e1)].set(r, mode="drop")
+            wslot2 = witness_row(wslot, r, ws, owned[:, 0])
+            return (rnd2, t, wslot2, n_un - owned.sum(dtype=I32), it,
+                    fallbacks)
+
+        def fallback(_):
+            return full_round(r, rnd, wslot, steps) + (fallbacks + 1,)
+
+        rnd, pos, wslot, n_un, steps, fallbacks = jax.lax.cond(
+            ran_out, fallback, band_round, None)
+        return r + 1, rnd, pos, wslot, n_un, steps, fallbacks
 
     def cond(carry):
-        r, _, _, _, alive, _ = carry
+        r, n_un = carry[0], carry[4]
         # rounds 0..r_cap-1 are assignable (wslot rows 0..r_cap-1, same
         # as the level scan); `r < r_cap - 1` here was an off-by-one that
         # silently dropped the top round at tight capacities
-        return alive & (r < r_cap)
+        return (n_un > 0) & (r < r_cap)
 
-    unassigned0 = valid_e & ~seeded
-    _, rnd, _, wslot, _, closure_steps = jax.lax.while_loop(
+    zero = jnp.asarray(0, I32)
+    _, rnd, _, wslot, _, closure_steps, band_fallbacks = jax.lax.while_loop(
         cond, round_step,
-        (jnp.asarray(0, I32), rnd0, unassigned0, wslot0,
-         jnp.asarray(True), jnp.asarray(0, I32)),
+        (zero, rnd0, jnp.zeros(B, I32), wslot0,
+         (valid_e & ~seeded).sum(dtype=I32), zero, zero),
     )
 
+    # a fresh buffer, not the loop's carry: the compiler then keeps it in
+    # the chip's fast memory for order's loop, which reads it every round
+    rnd = jnp.where(valid_e, rnd, -1)
     wit = valid_e & ((b.sp < 0) | (rnd > rnd[spx]))
     wit = jnp.where(b.wseed >= 0, b.wseed == 1, wit) & valid_e
     max_round = jnp.max(jnp.where(valid_e, rnd, -1))
-    return rnd, wit, wslot, max_round, closure_steps
+    return rnd, wit, wslot, max_round, closure_steps, band_fallbacks
 
 
 def _rounds_scan(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray,
@@ -1105,8 +1203,8 @@ def fork_pipeline_impl(cfg: ForkConfig, b: ForkBatch) -> ForkOut:
                 fd = _fd_chains(cfg, b, la)
         helper = _helper(cfg, b, fd, first_det)
         with jax.named_scope("rounds"):
-            rnd, wit, wslot, max_round, closure_steps = _rounds_closure(
-                cfg, b, la, det, helper)
+            (rnd, wit, wslot, max_round, closure_steps,
+             band_fallbacks) = _rounds_closure(cfg, b, la, det, helper)
     with jax.named_scope("babble_fame"):
         famous, lcr, vote_steps = _fame(cfg, b, la, det, helper, wslot,
                                         max_round)
@@ -1117,6 +1215,7 @@ def fork_pipeline_impl(cfg: ForkConfig, b: ForkBatch) -> ForkOut:
         la=la, det=det, fd=fd, round=rnd, witness=wit, wslot=wslot,
         famous=famous, rr=rr, cts=cts, max_round=max_round, lcr=lcr,
         closure_steps=closure_steps, vote_steps=vote_steps,
+        band_fallbacks=band_fallbacks,
     )
 
 

@@ -168,7 +168,7 @@ def fork_out_specs():
         la=P("ev", "p"), det=P("ev", None), fd=P("ev", "p"),
         round=ev, witness=ev, wslot=P(None, "p"), famous=P(None, "p"),
         rr=ev, cts=ev, max_round=P(), lcr=P(),
-        closure_steps=P(), vote_steps=P(),
+        closure_steps=P(), vote_steps=P(), band_fallbacks=P(),
     )
 
 

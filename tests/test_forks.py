@@ -13,6 +13,8 @@ insert (hashgraph.go:366-396) and skips fork detection in See
 (hashgraph.go:149-154).
 """
 
+from types import SimpleNamespace
+
 import pytest
 
 from babble_tpu.consensus.byzantine import ForkOracle
@@ -156,53 +158,156 @@ def test_fd_reverse_matches_chain_counts():
     assert (a == c).all(), f"{int((a != c).sum())} fd mismatches"
 
 
-@pytest.mark.parametrize("seed,tight", [(3, False), (9, True), (21, True)])
-def test_rounds_closure_matches_level_scan(seed, tight):
-    """_rounds_closure (the per-round closure iteration that replaced the
-    level scan for speed) must agree with _rounds_scan bit-for-bit —
-    including at TIGHT r_cap = max_round + 1, the capacity where an
-    off-by-one in the closure's loop bound silently dropped the top
-    round (caught in review; this test is the regression anchor)."""
-    import functools
-
+def _fresh_rounds(cfg, batch):
+    """The inputs of the rounds pass for ``batch`` with its round and
+    witness seeds cleared, so the pass decides every event itself."""
     import jax
-    import numpy as np
+    import jax.numpy as jnp
 
     from babble_tpu.ops import forks as F
 
-    dag = random_byzantine_dag(9, 400, seed=seed, fork_rate=0.06)
-    fh = ForkHashgraph(dag.participants, k=2)
-    for ev in dag.events:
-        fh.insert_event(ev.clone())
-    cfg, _ = fh._run()
+    batch = batch._replace(rseed=jnp.full_like(batch.rseed, -1),
+                           wseed=jnp.full_like(batch.wseed, -1))
 
-    def run(cfg):
-        batch = fh.dag.build_batch(cfg)
-        la = jax.jit(lambda b: F._la_scan(cfg, b))(batch)
-        det = jax.jit(lambda b, l: F._detect(cfg, b, l))(batch, la)
-        fdet = jax.jit(lambda b, d: F._first_det(cfg, b, d))(batch, det)
-        fd = jax.jit(lambda b: F._fd_reverse(cfg, b))(batch)
-        helper = jax.jit(lambda b, f, fr: F._helper(cfg, b, f, fr))(
-            batch, fd, fdet
-        )
-        scan = jax.jit(functools.partial(F._rounds_scan, cfg))(
-            batch, la, det, helper
-        )
-        clos = jax.jit(functools.partial(F._rounds_closure, cfg))(
-            batch, la, det, helper
-        )
-        return scan, clos
+    def inputs(b):
+        la = F._la_scan(cfg, b)
+        det = F._detect(cfg, b, la)
+        helper = F._helper(cfg, b, F._fd_reverse(cfg, b),
+                           F._first_det(cfg, b, det))
+        return la, det, helper
 
-    scan, clos = run(cfg)
-    if tight:
-        cfg = cfg._replace(r_cap=int(scan[3]) + 1)
-        scan, clos = run(cfg)
+    return (batch,) + jax.jit(inputs)(batch)
+
+
+def _closure_vs_scan(cfg, batch, band):
+    """_rounds_scan's and _rounds_closure's (round, witness, wslot,
+    max_round) on a fresh ``batch``, and the closure's band fallbacks."""
+    import functools
+
+    import jax
+
+    from babble_tpu.ops import forks as F
+
+    args = _fresh_rounds(cfg, batch)
+    scan = jax.jit(functools.partial(F._rounds_scan, cfg))(*args)
+    kw = {} if band is None else {"band": band}
+    clos = jax.jit(functools.partial(F._rounds_closure, cfg, **kw))(*args)
+    return scan, clos[:4], int(clos[5])
+
+
+def _assert_same_rounds(scan, clos):
+    import numpy as np
+
     for name, a, b in zip(("round", "witness", "wslot", "max_round"),
                           scan, clos):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b), err_msg=name
         )
+
+
+@pytest.mark.parametrize("band", [None, 2], ids=["default_band", "band2"])
+@pytest.mark.parametrize("seed,tight", [(3, False), (9, True), (21, True)])
+def test_rounds_closure_matches_level_scan(seed, tight, band):
+    """_rounds_closure (the per-round closure iteration that replaced the
+    level scan for speed) must agree with _rounds_scan bit-for-bit —
+    including at TIGHT r_cap = max_round + 1, the capacity where an
+    off-by-one in the closure's loop bound silently dropped the top
+    round (caught in review; this test is the regression anchor).  At
+    the default band width every round stays in its band; a band of 2
+    positions runs out and the full pass decides the round, exactly."""
+    dag = random_byzantine_dag(9, 400, seed=seed, fork_rate=0.06)
+    fh = ForkHashgraph(dag.participants, k=2)
+    for ev in dag.events:
+        fh.insert_event(ev.clone())
+    cfg, _ = fh._run()
+    batch = fh.dag.build_batch(cfg)
+
+    scan, clos, fallbacks = _closure_vs_scan(cfg, batch, band)
+    if tight:
+        cfg = cfg._replace(r_cap=int(scan[3]) + 1)
+        scan, clos, fallbacks = _closure_vs_scan(
+            cfg, fh.dag.build_batch(cfg), band)
+    _assert_same_rounds(scan, clos)
     assert int(scan[3]) >= 1
+    if band is None:
+        assert fallbacks == 0
+    else:
+        assert fallbacks > 0
+
+
+def test_rounds_closure_falls_back_on_a_stalled_chain():
+    """Two of four validators gossip only with each other for a while:
+    no event of theirs can strongly see a supermajority, so each mints
+    more events in one round than the band holds.  The band runs out,
+    the full pass decides those rounds, and rounds, witnesses, fame and
+    order stay those of the level scan and of the oracle."""
+    import numpy as np
+
+    from babble_tpu.core.event import new_event
+    from babble_tpu.ops.forks import ROUND_BAND
+
+    n, forker = 4, 3
+    rng = np.random.default_rng(17)
+
+    def fake_pub(i):
+        return b"\x04" + i.to_bytes(32, "big") + bytes(32)
+
+    participants = {("0x" + fake_pub(i).hex().upper()): i for i in range(n)}
+    pubs = [fake_pub(i) for i in range(n)]
+    own = [[] for _ in range(n)]          # (hex, index) of each own event
+    events = []
+
+    def mint(recv, send, self_parent=None):
+        sp = own[recv][-1] if self_parent is None else self_parent
+        ts = 1_700_000_000_000_000_000 + len(events) * 2_000_000
+        ev = new_event([], (sp[0], own[send][-1][0]), pubs[recv],
+                       sp[1] + 1, timestamp=ts)
+        ev.r = int(rng.integers(1, 1 << 62))
+        ev.s = int(rng.integers(1, 1 << 62))
+        events.append(ev)
+        own[recv].append((ev.hex(), sp[1] + 1))
+
+    def gossip(steps):
+        for _ in range(steps):
+            recv = int(rng.integers(0, n))
+            send = int(rng.integers(0, n - 1))
+            mint(recv, send + (send >= recv))
+
+    for i in range(n):
+        ev = new_event([], ("", ""), pubs[i], 0,
+                       timestamp=1_700_000_000_000_000_000)
+        ev.r, ev.s = i + 1, i + 1
+        events.append(ev)
+        own[i].append((ev.hex(), 0))
+    gossip(60)
+    mint(forker, 0, self_parent=own[forker][-2])     # the equivocation
+    gossip(40)
+    for _ in range(ROUND_BAND + 16):                  # the stalled pair
+        mint(0, 1)
+        mint(1, 0)
+    gossip(120)
+
+    fo = ForkOracle(participants)
+    fh = ForkHashgraph(participants, k=2)
+    for ev in events:
+        fo.insert_event(ev.clone())
+        fh.insert_event(ev.clone())
+    fo.run_consensus()
+    fh.run_consensus()
+    per_round = {}
+    for ev in events:
+        key = (participants[ev.creator], fo.round(ev.hex()))
+        per_round[key] = per_round.get(key, 0) + 1
+    assert max(per_round.values()) > ROUND_BAND, "no chain stalled"
+    assert sum(len(v) for v in fo._fork_pairs.values()) > 0
+    _assert_match(SimpleNamespace(events=events), fo, fh)
+
+    cfg, out = fh._run()
+    assert int(out.band_fallbacks) > 0
+    scan, clos, fallbacks = _closure_vs_scan(
+        cfg, fh.dag.build_batch(cfg), None)
+    _assert_same_rounds(scan, clos)
+    assert fallbacks > 0
 
 
 def test_windowed_fork_engine_matches_unevicted():
@@ -247,6 +352,77 @@ def test_windowed_fork_engine_matches_unevicted():
         assert rolled.round(x) == plain.round(x), x
     # the gossip clock stays absolute across eviction
     assert rolled.known() == plain.known()
+
+
+@pytest.mark.parametrize("seed", [11, 9])
+def test_windowed_rounds_closure_band_matches_full_pass(seed, monkeypatch):
+    """The rolling window hands the rounds pass seeded rounds: retained
+    events above a round's frontier (``rseed > r``, the parent-round
+    lift) and fork common prefixes, one event in two branch views, both
+    inside the band.  On every window of a streamed byzantine DAG the
+    band's result equals the full pass's (a band of one position falls
+    back every round), and the windowed engine still orders as the
+    unevicted one."""
+    import functools
+
+    import jax
+    import numpy as np
+
+    from babble_tpu.consensus import fork_engine
+    from babble_tpu.ops import forks as F
+
+    windows = []
+
+    def recording(cfg, batch):
+        out = F.fork_pipeline(cfg, batch)
+        if (np.asarray(batch.rseed) >= 0).any():
+            windows.append((cfg, batch, out))
+        return out
+
+    monkeypatch.setattr(fork_engine, "fork_pipeline", recording)
+    dag = random_byzantine_dag(6, 600, seed=seed, fork_rate=0.05)
+    plain = ForkHashgraph(dag.participants, k=2)
+    rolled = ForkHashgraph(dag.participants, k=2, auto_compact=True,
+                           round_margin=1, seq_window=6, compact_min=16)
+    step = 50
+    committed_plain, committed_rolled = [], []
+    for i in range(0, len(dag.events), step):
+        for ev in dag.events[i:i + step]:
+            plain.insert_event(ev)
+            rolled.insert_event(rolled.read_wire_info(plain.to_wire(ev)))
+        committed_plain += [(e.hex(), e.round_received)
+                            for e in plain.run_consensus()]
+        committed_rolled += [(e.hex(), e.round_received)
+                             for e in rolled.run_consensus()]
+    assert rolled.dag.evicted > 0, "window never rolled"
+    assert committed_rolled == committed_plain
+
+    @functools.partial(jax.jit, static_argnums=(0, 3))
+    def rounds(cfg, batch, out, band):
+        helper = F._helper(cfg, batch, out.fd,
+                           F._first_det(cfg, batch, out.det))
+        return F._rounds_closure(cfg, batch, out.la, out.det, helper,
+                                 band=band)
+
+    lifted = shared = 0
+    for cfg, batch, out in windows:
+        assert int(out.band_fallbacks) == 0
+        full = rounds(cfg, batch, out, 1)
+        assert int(full[5]) > 0
+        _assert_same_rounds(
+            (out.round, out.witness, out.wslot, out.max_round), full[:4])
+        # an unassigned event whose other parent is seeded above its
+        # self-parent's round: the lift decides it inside the band
+        rseed, rnd = np.asarray(batch.rseed), np.asarray(out.round)
+        sp, op = np.asarray(batch.sp), np.asarray(batch.op)
+        new = (rseed < 0) & (sp >= 0) & (op >= 0)
+        lifted += int((new & (rseed[op] > rnd[sp])).sum())
+        # a fork's common prefix retained in the window
+        cp, s_off = np.asarray(batch.cp), np.asarray(batch.s_off)
+        off = ~np.eye(cfg.b, dtype=bool) & (cp > 0) & (cp < 2**31 - 1)
+        shared += int((off & (cp > s_off[:, None])).sum())
+    assert lifted > 0, "no seeded round above a frontier"
+    assert shared > 0, "no fork common prefix in a window"
 
 
 def test_laggard_chains_block_unsafe_eviction():
