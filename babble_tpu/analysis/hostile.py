@@ -1,6 +1,6 @@
 """Trust-boundary taint: ``unbounded-hostile-input``.
 
-The byzantine 1.1 TB OOM (BENCH_r05) and the forged-snapshot hardening
+The byzantine batch's 1.1 TB OOM and the forged-snapshot hardening
 (ISSUE 8, PR 15) are the same bug class seen twice: a *peer-chosen
 integer* — a declared window size, a branch extent, a round seed —
 flowed into an allocation shape or a loop bound before anything checked

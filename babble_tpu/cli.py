@@ -195,11 +195,9 @@ async def start_node(args):
         wal_dir=getattr(args, "wal_dir", ""),
         wal_fsync=getattr(args, "wal_fsync", "batch"),
         kernel_class=getattr(args, "kernel_class", "auto"),
-        # kernel working-set diet (ROADMAP item 4): both pins are
-        # bit-parity-preserving — they select kernel math, not
-        # semantics (bench.py diet runs the before/after arms)
-        packed_votes=not getattr(args, "no_packed_votes", False),
-        frontier=not getattr(args, "no_frontier", False),
+        # packed_votes / frontier keep Config's defaults (on): the
+        # pre-diet f32 tallies and full-height scans are bit-identical
+        # and remain only as the tests' references
         # AOT prewarm shares the jit-cache root: the shape manifest
         # sits beside the persistent XLA cache it replays into
         aot_dir=(
@@ -921,14 +919,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="compiled-surface pin for the fused engine: "
                          "auto picks the small-batch latency kernel for "
                          "gossip-sized flushes, throughput for bulk")
-    rn.add_argument("--no_packed_votes", action="store_true",
-                    help="pin the pre-diet f32 vote tallies on the "
-                         "fused latency kernel (bit-identical; the "
-                         "packed popcount path is the default)")
-    rn.add_argument("--no_frontier", action="store_true",
-                    help="pin full-height fd scans in the windowed "
-                         "order phase (bit-identical; the event-axis "
-                         "frontier slice is the default)")
     rn.add_argument("--no_aot_prewarm", action="store_true",
                     help="skip AOT pre-compilation of recorded live-flush "
                          "shapes at boot (the persistent jit cache still "

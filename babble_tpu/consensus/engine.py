@@ -159,8 +159,7 @@ class TpuHashgraph:
         # the DagConfig (it selects kernel math inside the compiled
         # programs), frontier is engine policy (it only sizes the F
         # bucket the order phase scans).  Both are bit-parity-preserving
-        # — False pins the pre-diet kernels for differential tests and
-        # the bench's before/after arms.
+        # — False pins the pre-diet kernels for differential tests.
         self.cfg = DagConfig(n=n, e_cap=e_cap, s_cap=s_cap, r_cap=r_cap,
                              ts32=ts32, packed=packed_votes)
         self.state: DagState = init_state(self.cfg)

@@ -106,7 +106,7 @@ _baseline_tried = False
 
 
 def load_baseline() -> Optional[ctypes.CDLL]:
-    """The C++ reference-algorithm consensus baseline (bench-only)."""
+    """The C++ reference-algorithm consensus baseline (the tests' reference)."""
     global _baseline_lib, _baseline_tried
     if _baseline_lib is not None or _baseline_tried:
         return _baseline_lib

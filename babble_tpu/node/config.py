@@ -111,8 +111,7 @@ class Config:
     # Bit-packed votes: the fused latency kernel's see/strongly-see/
     # vote tallies run over 8:1 uint8 lanes with popcount
     # supermajorities instead of f32 einsums.  Bit-parity-preserving;
-    # False pins the pre-diet f32 tally (differential tests, the
-    # bench's before/after arm).
+    # False pins the pre-diet f32 tally (differential tests).
     packed_votes: bool = True
     # Event-axis frontier: the windowed order phase scans only the
     # F-row frontier slice of fd (power-of-two-bucketed live frontier
@@ -170,8 +169,8 @@ class Config:
     # ledger (obs/lineage.py) keyed on the hashes consensus already
     # computes — served loopback-gated at /debug/lineage?tx= and
     # stitched fleet-wide by `fleet trace <txid>`.  Zero wire or
-    # consensus changes; False turns every hook into a no-op (the
-    # bench's tracing-overhead A/B switch).
+    # consensus changes; False turns every hook into a no-op (a
+    # tracing-overhead A/B switch).
     lineage: bool = True
     # Flight recorder: a bounded ring of structured state-transition
     # records (obs/flight.py) — epoch applies, eviction horizon

@@ -64,7 +64,7 @@ class LineageRecorder:
     """Bounded per-key lifecycle ledger (see module docstring).  Safe
     from the event loop and worker threads; every mutation is a few
     instructions under one lock.  ``enabled=False`` turns every hook
-    into a cheap no-op (the bench's tracing-overhead A/B switch)."""
+    into a cheap no-op (a tracing-overhead A/B switch)."""
 
     def __init__(self, capacity: int = 4096, per_key: int = 64,
                  enabled: bool = True):

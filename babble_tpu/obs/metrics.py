@@ -408,8 +408,8 @@ class Registry:
         )
 
     def snapshot(self) -> dict:
-        """JSON-able dump of every family — the form bench artifacts
-        embed so a degraded round carries its own evidence."""
+        """JSON-able dump of every family, for embedding a registry's
+        state in a run's record."""
         out = {}
         for fam in self.families():
             series = []

@@ -1,8 +1,8 @@
 """AOT compilation cache for the consensus kernels.
 
-Kills the cold-start tax (ROADMAP item 3): every BENCH_r0* config paid
-19-37 s of XLA compile+first-run, bench r04 blew a 1500 s watchdog on
-it, and a fleet restart re-paid the whole bill.  Three layers:
+Kills the cold-start tax (ROADMAP item 3): every early benchmark config
+paid 19-37 s of XLA compile+first-run, one run blew a 1500 s watchdog
+on it, and a fleet restart re-paid the whole bill.  Three layers:
 
 1. **Persistent XLA cache** (``configure``): jax's compilation cache
    directory, so a recompile of an already-seen program is a

@@ -44,6 +44,15 @@ from .state import (
     retired_mask, sanitize, set_sentinel,
 )
 
+#: The ingest modes ``ingest_impl`` accepts (see its docstring).  The
+#: mode is static, so an unknown one fails at trace time.
+FD_MODES = ("incremental", "full", "fast", "walk")
+
+
+def _check_fd_mode(fd_mode: str) -> None:
+    if fd_mode not in FD_MODES:
+        raise ValueError(f"unknown fd_mode {fd_mode!r}; one of {FD_MODES}")
+
 
 class EventBatch(NamedTuple):
     """Host-built arrays for K new events (padded to a bucketed size).
@@ -398,79 +407,6 @@ def _rounds_level_scan(
     return state._replace(round=rnd, witness=wit, wslot=wslot, max_round=max_round)
 
 
-def _la_init_direct(state: DagState, cfg: DagConfig, b: EventBatch) -> DagState:
-    """Seed new events' last-ancestor rows with their *direct* parent
-    positions only (own seq at own creator, each parent's seq at its
-    creator); _la_absorb closes the transitive reachability."""
-    kpad = b.sp.shape[0]
-    pos = jnp.arange(kpad, dtype=I32)
-    real = pos < b.k
-    slots = jnp.where(real, state.n_events - b.k + pos, cfg.e_cap)
-
-    rows = jnp.full((kpad, cfg.n), -1, cfg.coord_dtype)
-    own = jnp.clip(b.creator, 0, cfg.n - 1)
-    rows = rows.at[jnp.arange(kpad), own].max(b.seq.astype(rows.dtype))
-    # Missing parents (slot -1) must contribute nothing.  The sentinel row is
-    # NOT trustworthy here: this runs right after _write_batch_fields, whose
-    # padded lanes dumped zero-filled creator/seq into row e_cap — gathering
-    # it would plant a phantom "sees creator 0 at seq 0" on every root event.
-    # Mask on parent validity instead.
-    spx = sanitize(b.sp, cfg.e_cap)
-    opx = sanitize(b.op, cfg.e_cap)
-    sp_c = jnp.clip(state.creator[spx], 0, cfg.n - 1)
-    op_c = jnp.clip(state.creator[opx], 0, cfg.n - 1)
-    sp_seq = jnp.where(b.sp >= 0, state.seq[spx], -1).astype(rows.dtype)
-    op_seq = jnp.where(b.op >= 0, state.seq[opx], -1).astype(rows.dtype)
-    rows = rows.at[jnp.arange(kpad), sp_c].max(sp_seq)
-    rows = rows.at[jnp.arange(kpad), op_c].max(op_seq)
-    # Padded lanes all dump into the sentinel row; their rows must stay -1.
-    rows = jnp.where(real[:, None], rows, -1)
-    return state._replace(la=state.la.at[slots].set(rows))
-
-
-def _la_absorb(state: DagState, cfg: DagConfig) -> DagState:
-    """Close last-ancestor rows by frontier self-absorption:
-
-        la[x, j] <- max(la[x, j], max_k la[ce[k, la[x, k]], j])
-
-    Each pass composes reachability with itself, so convergence takes
-    O(log(depth)) full passes instead of the level scan's O(depth)
-    sequential steps — the difference between ~12 and ~3500 kernel
-    iterations on a 65k-event gossip DAG.  Already-converged rows (old
-    events) are fixpoints, so appending batches is safe."""
-    n, s_cap = cfg.n, cfg.s_cap
-    cols = jnp.arange(n)
-    spx = sanitize(state.sp, cfg.e_cap)
-    opx = sanitize(state.op, cfg.e_cap)
-
-    s_off = state.s_off[:n]
-
-    def absorb(la):
-        # Cross-chain: absorb the rows of the frontier events (the deepest
-        # event seen per chain).  The own-chain frontier is the event
-        # itself, so the direct parents' rows are absorbed explicitly —
-        # that's what propagates knowledge down the self-chain.  la values
-        # are absolute seqs; ce columns are window-local (frontier events
-        # below a rolled window gather the sentinel and contribute nothing
-        # — their knowledge is already in the converged parent rows).
-        wi = la - s_off[None, :]
-        fr = state.ce[cols[None, :], jnp.where((la >= 0) & (wi >= 0), wi, s_cap)]
-        absorbed = la[sanitize(fr, cfg.e_cap)]            # [E+1, N, N]
-        out = jnp.maximum(la, absorbed.max(axis=1))
-        return jnp.maximum(out, jnp.maximum(la[spx], la[opx]))
-
-    def cond(c):
-        return c[1]
-
-    def body(c):
-        la, _ = c
-        la2 = absorb(la)
-        return la2, (la2 != la).any()
-
-    la, _ = jax.lax.while_loop(cond, body, (state.la, jnp.asarray(True)))
-    return state._replace(la=la)
-
-
 def frontier_init(state: DagState, cfg: DagConfig):
     """Initial carry of the witness-frontier march."""
     n, r_cap = cfg.n, cfg.r_cap
@@ -604,7 +540,7 @@ def _rounds_frontier(state: DagState, cfg: DagConfig) -> DagState:
     Window note: the march starts from each chain's window base and round
     r_off, so it is only exact when the window base IS the round-r_off
     witness frontier — true for fresh states (all offsets zero), which is
-    the only way the engine reaches this path ('fast'/'absorb' batch
+    the only way the engine reaches this path ('fast'/'walk' batch
     modes).  The live rolled-window path uses the incremental level scan.
 
     NB for wide participant axes: data-dependent gathers from the [E, N]
@@ -643,6 +579,7 @@ def ingest_coords_impl(
     The last-ancestor fill runs under ``named_scope("la")`` and the
     first-descendant fill under ``named_scope("fd")``, whichever form
     the mode takes, so a profile splits ingest's device time by fill."""
+    _check_fd_mode(fd_mode)
     state = _write_batch_fields(state, cfg, batch)
 
     def _fd_batch(state, slot_sched):
@@ -673,13 +610,6 @@ def ingest_coords_impl(
         with jax.named_scope("fd"):
             state = _fd_batch(state, slot_sched)
         return _reset_coord_sentinels(state, cfg)
-    if fd_mode == "absorb":
-        with jax.named_scope("la"):
-            state = _la_absorb(_la_init_direct(state, cfg, batch), cfg)
-        state = _fd_init_own(state, cfg, batch)
-        with jax.named_scope("fd"):
-            state = _fd_full(state, cfg)
-        return _reset_coord_sentinels(state, cfg)
     slot_sched = _slot_sched(state.n_events - batch.k, cfg, batch.sched)
     with jax.named_scope("la"):
         state = _la_level_scan(state, cfg, slot_sched)
@@ -691,7 +621,7 @@ def ingest_coords_impl(
             # batch path: the schedule covers the whole DAG, so the
             # cheaper of reverse scan / compare-count applies (_fd_batch)
             state = _fd_batch(state, slot_sched)
-        else:
+        elif fd_mode == "full":
             state = _fd_full(state, cfg)
     return _reset_coord_sentinels(state, cfg)
 
@@ -705,10 +635,11 @@ def ingest_rounds_impl(
     boundary as donated arguments instead of XLA remat-copy temps —
     one such copy was 3.8 GB at 10k x 100k).  Runs under
     ``named_scope("rounds")``."""
+    _check_fd_mode(fd_mode)
     with jax.named_scope("rounds"):
-        if fd_mode in ("walk", "absorb", "fast"):
+        if fd_mode in ("walk", "fast"):
             state = _rounds_frontier(state, cfg)
-        else:
+        elif fd_mode in ("incremental", "full"):
             slot_sched = _slot_sched(
                 state.n_events - batch.k, cfg, batch.sched
             )
@@ -731,9 +662,8 @@ def ingest_impl(cfg: DagConfig, state: DagState, fd_mode: str, batch: EventBatch
       sequential-walk kernel (pallas_ingest.la_walk) instead of the level
       scan: one in-VMEM pass over the slot order, ~1.8x faster than the
       ~3,500-launch scan at 64x65k.  Gated by walk_supported().
-    - 'absorb'      — like 'fast' but with log-depth la self-absorption
-      instead of the level scan; gather-bound on current XLA — superseded
-      by 'walk'.
+
+    Any other mode raises ValueError at trace time (FD_MODES).
     """
     state = ingest_coords_impl(cfg, state, fd_mode, batch)
     return ingest_rounds_impl(cfg, state, fd_mode, batch)

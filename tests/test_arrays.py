@@ -57,11 +57,11 @@ def test_build_schedule_levels():
         assert (dag.levels[lv] == row).all()
 
 
-@pytest.mark.parametrize("fd_mode", ["fast", "absorb", "incremental"])
+@pytest.mark.parametrize("fd_mode", ["fast", "incremental"])
 def test_fd_modes_match_full(fd_mode):
     """Every selectable fd_mode of ingest_impl must produce bit-identical
-    consensus tensors to the 'full' reference path.  Regression: 'absorb'
-    once planted phantom la entries from sentinel-row junk (round-1 bug)."""
+    consensus tensors to the 'full' reference path ('walk' is checked by
+    test_walk_mode_matches_fast)."""
     import jax
 
     from babble_tpu.ops.state import (
@@ -81,6 +81,24 @@ def test_fd_modes_match_full(fd_mode):
         init_state(cfg), batch
     )
     assert_consensus_parity(ref, out, e, label=f"fd_mode={fd_mode}")
+
+
+@pytest.mark.parametrize("fd_mode", ["absorb", "fsat"])
+def test_ingest_rejects_an_unknown_fd_mode(fd_mode):
+    """A mode outside ingest.FD_MODES (a retired one, a misspelling) is
+    refused when the step is traced instead of silently running another
+    algorithm."""
+    from babble_tpu.ops.ingest import FD_MODES, ingest_impl, ingest_rounds_impl
+    from babble_tpu.ops.state import DagConfig, init_state
+
+    assert fd_mode not in FD_MODES
+    dag = random_gossip_arrays(4, 40, seed=1)
+    cfg = DagConfig(n=4, e_cap=40, s_cap=dag.max_chain + 1, r_cap=8)
+    batch = batch_from_arrays(dag)
+    with pytest.raises(ValueError, match="fd_mode"):
+        ingest_impl(cfg, init_state(cfg), fd_mode, batch)
+    with pytest.raises(ValueError, match="fd_mode"):
+        ingest_rounds_impl(cfg, init_state(cfg), fd_mode, batch)
 
 
 def test_array_path_matches_engine_path():
@@ -126,7 +144,7 @@ def test_array_path_matches_engine_path():
 
 @pytest.mark.parametrize("n,e,seed", [(4, 200, 0), (8, 500, 3), (16, 1500, 9)])
 def test_cpp_baseline_matches_tpu_engine(n, e, seed):
-    """The C++ reference-algorithm baseline (bench denominator) must agree
+    """The C++ reference-algorithm baseline (the tests' reference) must agree
     with the TPU pipeline on rounds, witnesses, round-received, consensus
     timestamps, and witness fame."""
     import functools

@@ -17,11 +17,18 @@ first argument of a ``.counter(...)`` / ``.gauge(...)`` /
 ``.histogram(...)`` call, or the first element of a
 ``("babble_x", "stats_key")`` mirror tuple (node/core.py registers the
 /Stats mirror gauges from such a table).
+
+The README and the verify skill must also name only files that exist:
+a deleted script or record named in backticks fails here.
 """
 
 import ast
+import fnmatch
+import glob
 import os
 import re
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(REPO, "babble_tpu")
@@ -117,4 +124,54 @@ def test_registered_metrics_are_documented():
     assert undocumented == [], (
         "metrics registered by code but absent from README "
         f"(document them in the Key series table): {undocumented}"
+    )
+
+
+_DOC_FILE_EXTS = (".py", ".json", ".md", ".cpp")
+# files the program writes at run time, named in the docs as outputs
+_RUNTIME_FILES = {"peers.json", "babble_aot_manifest.json"}
+
+
+def _tree_files():
+    out = []
+    for root, dirs, files in os.walk(REPO):
+        dirs[:] = [d for d in dirs if d != ".git"]
+        out.extend(files)
+    return out
+
+
+def _backticked_file_names(text):
+    """Backtick spans that are one file name (no command around it),
+    any ``::test`` suffix dropped."""
+    names = set()
+    for span in re.findall(r"`([^`\s]+)`", text):
+        name = span.split("::")[0]
+        if (name.endswith(_DOC_FILE_EXTS) and "<" not in name
+                and name not in _RUNTIME_FILES):
+            names.add(name)
+    return names
+
+
+def _named_file_exists(name, tree_files):
+    if "/" not in name:
+        return any(fnmatch.fnmatch(f, name) for f in tree_files)
+    return any(
+        glob.glob(os.path.join(REPO, base, name))
+        for base in ("", "babble_tpu", "benchmark")
+    )
+
+
+@pytest.mark.parametrize(
+    "doc", ["README.md", os.path.join(".claude", "skills", "verify",
+                                      "SKILL.md")])
+def test_doc_named_files_exist(doc):
+    with open(os.path.join(REPO, doc), encoding="utf-8") as f:
+        names = _backticked_file_names(f.read())
+    assert names, f"{doc} names no file in backticks"
+    tree_files = _tree_files()
+    missing = sorted(n for n in names
+                     if not _named_file_exists(n, tree_files))
+    assert missing == [], (
+        f"{doc} names files that do not exist (deleted or renamed?): "
+        f"{missing}"
     )

@@ -344,46 +344,3 @@ def test_loop_lag_probe_records_samples():
 
     asyncio.run(go())
 
-
-# ----------------------------------------------------------------------
-# registry snapshot diffing (ISSUE 3 satellite: bench.py attribution)
-
-
-def test_registry_diff_attributes_counter_and_histogram_deltas():
-    import bench
-
-    r = Registry()
-    c = r.counter("babble_submitted_tx_total", "txs")
-    h = r.histogram("babble_phase_seconds", "phase",
-                    labelnames=("phase",))
-    h.labels("ingest").observe(0.25)
-    before = r.snapshot()
-
-    c.inc(5)
-    h.labels("ingest").observe(0.75)
-    h.labels("order").observe(1.0)
-    after = r.snapshot()
-
-    diff = bench.registry_diff(before, after)
-    by_key = {
-        (row["metric"], tuple(sorted(row["labels"].items()))): row
-        for row in diff["rows"]
-    }
-    assert by_key[("babble_submitted_tx_total", ())]["delta"] == 5
-    ingest = by_key[("babble_phase_seconds", (("phase", "ingest"),))]
-    assert ingest["delta_count"] == 1          # the pre-existing 0.25
-    assert ingest["delta_sum"] == pytest.approx(0.75)  # is subtracted out
-    order = by_key[("babble_phase_seconds", (("phase", "order"),))]
-    assert order["delta_count"] == 1 and order["delta_sum"] == 1.0
-    # shares attribute the histogram seconds between the two snapshots
-    assert diff["total_hist_sum"] == pytest.approx(1.75)
-    assert ingest["share"] + order["share"] == pytest.approx(1.0)
-    # rows are sorted most-expensive-first for the attribution table
-    assert diff["rows"][0] is by_key[
-        ("babble_phase_seconds", (("phase", "order"),))
-    ]
-    # unchanged series are omitted entirely
-    assert bench.registry_diff(after, after)["rows"] == []
-    # and the text table renders every row
-    table = bench.format_attribution(diff)
-    assert "babble_phase_seconds" in table and "phase=order" in table
