@@ -52,7 +52,9 @@ import numpy as np
 
 from ..core.dag import clamp_eff_ts
 from ..core.event import Event
-from .state import I32, I64, INT32_MAX, sanitize, set_sentinel
+from .state import (
+    I32, I64, INT32_MAX, count_below_sorted, sanitize, set_sentinel,
+)
 from ..membership.quorum import supermajority
 
 F32 = jnp.float32
@@ -627,77 +629,29 @@ def _fd_reverse(cfg: ForkConfig, b: ForkBatch) -> jnp.ndarray:
 
 
 def _fd_chains(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray) -> jnp.ndarray:
-    """fd[y, br] = first chain-(br) index of a descendant of y (compare-
-    count over the monotone chain view, the _fd_full pattern with a branch
-    axis).
+    """fd[y, br] = first chain-(br) index of a descendant of y: the count,
+    on chain br, of positions whose event is not yet a descendant of y,
+    over the monotone chain view (state.count_below_sorted, the _fd_full
+    pattern with a branch axis).  V is k*E*B*4 bytes, small wherever the
+    cost model picks this form over _fd_reverse."""
+    s_idx = jnp.arange(cfg.s_cap + 1)
+    live = s_idx[None, :] < b.cnt[:, None]                    # [B, S+1]
+    V = jnp.where(live[:, :, None], la[sanitize(b.ce, cfg.e_cap)],
+                  INT32_MAX)                                  # [B, S+1, B]
+    # thresholds are ABSOLUTE target-chain indexes (window position t on
+    # chain `by` is index t + s_off[by])
+    out = count_below_sorted(V, s_idx[None, :] + b.s_off[:, None])
+    found = out < b.cnt[:, None, None]
+    # counts are window positions on the source chain -> absolute
+    out = jnp.where(found, out + b.s_off[:, None, None], INT32_MAX)
 
-    Memory shape: the full [B(chain), S+1, B(target)] gather and the
-    [B, B, T] count grid are ~4 GB each at the byzantine bench size
-    (B=2048), so the chain axis is processed in column chunks: each chunk
-    gathers its V slab, counts against every threshold, and lands in its
-    own fd column block via dynamic_update_slice (blocks are disjoint)."""
-    B, s_cap = cfg.b, cfg.s_cap
-    e1 = cfg.e_cap + 1
-    s_idx = jnp.arange(s_cap + 1)
-    t_total = s_cap + 1
-
-    # chain chunk size: keep the [Cb, S+1, B] V slab and [Cb, B, T] counts
-    # under ~0.5 GB each
-    cb = max(1, min(B, 2 ** 27 // max(1, (s_cap + 1) * B)))
-    n_cb = -(-B // cb)
-    cbpad = n_cb * cb
-
-    ce_p = jnp.concatenate(
-        [b.ce, jnp.full((cbpad - B, s_cap + 1), -1, I32)], axis=0
-    )
-    cnt_p = jnp.concatenate([b.cnt, jnp.zeros(cbpad - B, I32)], axis=0)
-
-    # per-threshold inner chunking bounds the compare broadcast
-    tc = max(1, min(t_total, 2 ** 27 // max(1, cb * (s_cap + 1) * B)))
-    n_tc = -(-t_total // tc)
-    tpad = n_tc * tc
-
-    # all-chains owned-target grid (rows disjoint across chains)
+    # fd[ce[by, t], br] = out[br, by, t] over the owned-target grid (rows
+    # disjoint across chains)
     tgt = sanitize(jnp.where(b.owner, b.ce, -1), cfg.e_cap)   # [B, S+1]
-
-    # fd columns padded to the chunk grid so dynamic_update_slice never
-    # clamps the last chunk's start; sliced back to B at the end
-    fd = jnp.full((e1, cbpad), INT32_MAX, I32)
-    for c0 in range(0, B, cb):
-        ce_c = jax.lax.dynamic_slice(ce_p, (c0, 0), (cb, s_cap + 1))
-        cnt_c = jax.lax.dynamic_slice(cnt_p, (c0,), (cb,))
-        V = la[sanitize(ce_c, cfg.e_cap)]                     # [Cb, S+1, B]
-        V = jnp.where(
-            (s_idx[None, :] < cnt_c[:, None])[:, :, None], V, INT32_MAX
-        )
-
-        s_off_c = jax.lax.dynamic_slice(
-            jnp.concatenate([b.s_off, jnp.zeros(cbpad - B, I32)]), (c0,),
-            (cb,),
-        )
-
-        def count_chunk(t0, V=V, s_off_c=s_off_c):
-            # thresholds are ABSOLUTE target-chain indexes (window
-            # position t on chain `by` is index t + s_off[by])
-            t_idx = t0 + jnp.arange(tc)
-            thr = t_idx[None, None, None, :] + b.s_off[None, None, :, None]
-            lt = V[:, :, :, None] < thr
-            return lt.sum(axis=1, dtype=I32)                  # [Cb, B, Tc]
-
-        counts = jax.lax.map(count_chunk, jnp.arange(n_tc) * tc)
-        out = jnp.moveaxis(counts, 0, 2).reshape(cb, B, tpad)[:, :, :t_total]
-        found = out < cnt_c[:, None, None]
-        # counts are window positions on the source chain -> absolute
-        out = jnp.where(found, out + s_off_c[:, None, None], INT32_MAX)
-
-        # land this chunk's columns: fd[ce[by, t], c0:c0+cb] = out[br, by, t]
-        block = jnp.full((e1, cb), INT32_MAX, I32)
-        block = block.at[tgt].set(out.transpose(1, 2, 0))     # [B, T, Cb]
-        block = set_sentinel(
-            block, (jnp.arange(e1) == cfg.e_cap)[:, None], INT32_MAX
-        )
-        fd = jax.lax.dynamic_update_slice(fd, block, (0, c0))
-    return fd[:, :B]
+    fd = jnp.full((cfg.e_cap + 1, cfg.b), INT32_MAX, I32)
+    fd = fd.at[tgt].set(out.transpose(1, 2, 0))               # [B, T, B]
+    e_row = (jnp.arange(cfg.e_cap + 1) == cfg.e_cap)[:, None]
+    return set_sentinel(fd, e_row, INT32_MAX)
 
 
 def _helper(cfg: ForkConfig, b: ForkBatch, fd: jnp.ndarray,

@@ -40,8 +40,8 @@ import jax.numpy as jnp
 from .pack import count_bits
 from .ss import ss_counts
 from .state import (
-    INT32_MAX, DagConfig, DagState, I32, I64, repack_round_bits,
-    retired_mask, sanitize, set_sentinel,
+    INT32_MAX, DagConfig, DagState, I32, I64, count_below_sorted,
+    repack_round_bits, retired_mask, sanitize, set_sentinel,
 )
 
 #: The ingest modes ``ingest_impl`` accepts (see its docstring).  The
@@ -254,8 +254,8 @@ def _fd_reverse_scan(
         fd[p] = elementwise-min over children c of fd[c], plus own seq
 
     Cost is O(E·N) like the la scan (~0.8 s at 1M events) — it replaces
-    the chain-view compare-count (_fd_full) on the batch path, whose
-    O(N²·S²) = O(E²) blow-up costs ~12 s at 1M.  Requires the schedule to
+    the chain-view search (_fd_full) on the batch path where the cost
+    model (state.fd_reverse_scan_wins) says so.  Requires the schedule to
     cover the whole DAG (the 'fast'/'walk' batch modes); incremental and
     engine paths keep their own fd strategies."""
     def step(fd, idx):
@@ -268,19 +268,16 @@ def _fd_reverse_scan(
 
 
 def _fd_full(state: DagState, cfg: DagConfig) -> DagState:
-    """Full first-descendant recompute via chain-view searchsorted.
+    """Full first-descendant recompute via chain-view search.
 
     fd[y, j] = smallest s with la[ce[j, s], creator[y]] >= seq[y].  Key
     restructuring for TPU: events y of one creator c form the chain
     c with seq = 0..cnt[c]-1, and the lookup table V[j, s, c] =
     la[ce[j, s], c] is monotone non-decreasing in s — so
-    searchsorted(V[j, :, c], t) == |{s : V[j, s, c] < t}|, a *vectorized
-    compare-count* over the s axis.  The earlier binary-search version did
-    ~(N²·S·log S) take_along_axis gathers, which scalarize on TPU
-    (~20 ns/element: 21 s of a 25 s pipeline at 1024x100k); the count form
-    is pure broadcast-compare-reduce on the VPU (~10⁴x faster per element),
-    computed in t-chunks so the [N, S+1, N, Tc] broadcast never exceeds a
-    few hundred MB."""
+    searchsorted(V[j, :, c], t) == |{s : V[j, s, c] < t}|, which
+    state.count_below_sorted computes by a search in two levels over
+    128-position blocks (whole rows, never per-element gathers: those
+    run one at a time on TPU, ~20 ns each)."""
     n, s_cap = cfg.n, cfg.s_cap
     s_off = state.s_off[:n]                                      # [N]
     cnt_w = state.cnt[:n] - s_off                                # windowed lengths
@@ -295,23 +292,9 @@ def _fd_full(state: DagState, cfg: DagConfig) -> DagState:
         (s_idx[None, :] < cnt_w[:, None])[:, :, None], V, INT32_MAX
     )
 
-    # out[j, c, t] = |{s : V[j, s, c] < seq(c's event at window pos t)}|,
-    # reduced in chunks of t; the threshold is the absolute seq t + s_off[c]
-    t_total = s_cap + 1
-    # budget ~256 MB for the [N, S+1, N, Tc] broadcast in case XLA
-    # materializes it rather than fusing into the reduction
-    chunk = max(1, min(t_total, 2 ** 28 // max(1, n * n * (s_cap + 1))))
-    n_chunks = -(-t_total // chunk)
-    tpad = n_chunks * chunk
-
-    def count_chunk(t0):
-        t_idx = t0 + jnp.arange(chunk)                           # [Tc]
-        thr = t_idx[None, None, None, :] + s_off[None, None, :, None]
-        lt = V[:, :, :, None] < thr                              # [N,S+1,N,Tc]
-        return lt.sum(axis=1, dtype=I32)                         # [N, N, Tc]
-
-    counts = jax.lax.map(count_chunk, jnp.arange(n_chunks) * chunk)
-    out = jnp.moveaxis(counts, 0, 2).reshape(n, n, tpad)[:, :, :t_total]
+    # out[j, c, t] = |{s : V[j, s, c] < seq(c's event at window pos t)}|;
+    # the threshold is the absolute seq t + s_off[c]
+    out = count_below_sorted(V, s_idx[None, :] + s_off[:, None])
     found = out < cnt_w[:, None, None]
     # fd values are absolute seqs: window-local count + chain j's offset
     # INF must be the coordinate dtype's sentinel: a raw INT32_MAX
@@ -619,7 +602,7 @@ def ingest_coords_impl(
             state = _fd_incremental(state, cfg, batch)
         elif fd_mode == "fast":
             # batch path: the schedule covers the whole DAG, so the
-            # cheaper of reverse scan / compare-count applies (_fd_batch)
+            # cheaper of reverse scan / chain-view search applies (_fd_batch)
             state = _fd_batch(state, slot_sched)
         elif fd_mode == "full":
             state = _fd_full(state, cfg)

@@ -57,11 +57,7 @@ def test_build_schedule_levels():
         assert (dag.levels[lv] == row).all()
 
 
-@pytest.mark.parametrize("fd_mode", ["fast", "incremental"])
-def test_fd_modes_match_full(fd_mode):
-    """Every selectable fd_mode of ingest_impl must produce bit-identical
-    consensus tensors to the 'full' reference path ('walk' is checked by
-    test_walk_mode_matches_fast)."""
+def _assert_fd_mode_matches_full(fd_mode, n, e, seed, r_cap):
     import jax
 
     from babble_tpu.ops.state import (
@@ -69,9 +65,8 @@ def test_fd_modes_match_full(fd_mode):
     )
     from babble_tpu.parallel.sharded import consensus_step_impl
 
-    n, e = 6, 300
-    dag = random_gossip_arrays(n, e, seed=11)
-    cfg = DagConfig(n=n, e_cap=e, s_cap=dag.max_chain + 1, r_cap=32)
+    dag = random_gossip_arrays(n, e, seed=seed)
+    cfg = DagConfig(n=n, e_cap=e, s_cap=dag.max_chain + 1, r_cap=r_cap)
     batch = batch_from_arrays(dag)
 
     ref = jax.jit(functools.partial(consensus_step_impl, cfg, "full"))(
@@ -81,6 +76,82 @@ def test_fd_modes_match_full(fd_mode):
         init_state(cfg), batch
     )
     assert_consensus_parity(ref, out, e, label=f"fd_mode={fd_mode}")
+    return cfg
+
+
+@pytest.mark.parametrize("fd_mode", ["fast", "incremental"])
+def test_fd_modes_match_full(fd_mode):
+    """Every selectable fd_mode of ingest_impl must produce bit-identical
+    consensus tensors to the 'full' reference path ('walk' is checked by
+    test_walk_mode_matches_fast)."""
+    _assert_fd_mode_matches_full(fd_mode, 6, 300, 11, 32)
+
+
+@pytest.mark.parametrize("fd_mode", ["fast", "incremental"])
+def test_fd_modes_match_full_several_blocks(fd_mode):
+    """The same at chains of ~650 events, several 128-position blocks of
+    the chain view, where 'full' searches in both of its levels."""
+    from babble_tpu.ops.state import SORTED_BLOCK
+
+    cfg = _assert_fd_mode_matches_full(fd_mode, 4, 2600, 5, 256)
+    assert cfg.s_cap + 1 > 4 * SORTED_BLOCK
+
+
+def _plain_count(V, thr):
+    return (V[:, :, :, None] < thr[None, None]).sum(axis=1)
+
+
+@pytest.mark.parametrize("s1", [1, 127, 128, 129, 256, 700])
+@pytest.mark.parametrize("offset", [False, True])
+def test_count_below_sorted_matches_plain_count(s1, offset):
+    """The two-level search over the sorted chain view equals the plain
+    compare-count: non-decreasing columns holding -1 entries and an
+    INT32_MAX tail past a drawn length (one column full, so that at 256
+    a threshold lies past every block), thresholds t + s_off."""
+    import jax
+
+    from babble_tpu.ops.state import INT32_MAX, count_below_sorted
+
+    rng = np.random.default_rng(s1 * 2 + offset)
+    c, d = 3, 5
+    V = np.sort(rng.integers(-1, s1 + 4, (c, s1, d)), axis=1)
+    cnt = rng.integers(0, s1 + 1, c)
+    cnt[0] = s1
+    V = np.where(np.arange(s1)[None, :, None] < cnt[:, None, None], V,
+                 INT32_MAX).astype(np.int32)
+    s_off = rng.integers(1, 6, d) if offset else np.zeros(d, int)
+    thr = (np.arange(s1 + 1)[None, :] + s_off[:, None]).astype(np.int32)
+    got = np.asarray(jax.jit(count_below_sorted)(V, thr))
+    np.testing.assert_array_equal(got, _plain_count(V, thr))
+
+
+@pytest.mark.parametrize("s1", [100, 700])
+def test_count_below_sorted_in_chunks(monkeypatch, s1):
+    """The same with the threshold axis cut into many chunks, a last one
+    partly padding (nb = 1 takes the plain count, nb > 1 the search)."""
+    import jax
+
+    from babble_tpu.ops import state
+    from babble_tpu.ops.state import count_below_sorted
+
+    rng = np.random.default_rng(s1)
+    c, d = 2, 3
+    V = np.sort(rng.integers(-1, s1, (c, s1, d)), axis=1).astype(np.int32)
+    thr = (np.arange(s1 + 3)[None, :] + np.arange(d)[:, None]).astype(
+        np.int32)
+    monkeypatch.setattr(state, "_COUNT_CHUNK_ELEMS", 7 * c * d * s1)
+    got = np.asarray(jax.jit(lambda v, t: count_below_sorted(v, t))(V, thr))
+    np.testing.assert_array_equal(got, _plain_count(V, thr))
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_both_cells_take_the_chain_count(k):
+    """Both benchmark cells' shapes (38,912 schedule rows, 65,536 events;
+    the fork cell k = 2, the honest shape k = 1) run the chain-view
+    count, not the reverse scan."""
+    from babble_tpu.ops.state import fd_reverse_scan_wins
+
+    assert not fd_reverse_scan_wins(38_912, 65_536, k)
 
 
 @pytest.mark.parametrize("fd_mode", ["absorb", "fsat"])

@@ -224,13 +224,10 @@ def test_engine_capacity_growth():
     _compare_all(dag, oracle, engine)
 
 
-def test_fd_full_equals_incremental():
-    """The two first-descendant strategies must produce identical tensors."""
-    import jax.numpy as jnp
-
-    dag = random_gossip_dag(5, 100, seed=30)
-    e_inc = _engine_for(dag, e_cap=128, s_cap=64, r_cap=32)
-    e_full = _engine_for(dag, e_cap=128, s_cap=64, r_cap=32)
+def _assert_fd_full_equals_incremental(n, e, seed, e_cap, s_cap, r_cap):
+    dag = random_gossip_dag(n, e, seed=seed)
+    e_inc = _engine_for(dag, e_cap=e_cap, s_cap=s_cap, r_cap=r_cap)
+    e_full = _engine_for(dag, e_cap=e_cap, s_cap=s_cap, r_cap=r_cap)
     for ev in dag.events:
         e_inc.insert_event(ev)
         e_full.insert_event(ev)
@@ -251,3 +248,18 @@ def test_fd_full_equals_incremental():
     np.testing.assert_array_equal(
         np.asarray(e_inc.state.la), np.asarray(e_full.state.la)
     )
+    return e_full
+
+
+def test_fd_full_equals_incremental():
+    """The two first-descendant strategies must produce identical tensors."""
+    _assert_fd_full_equals_incremental(5, 100, 30, 128, 64, 32)
+
+
+def test_fd_full_equals_incremental_several_blocks():
+    """The same at chains of ~650 events, several 128-position blocks of
+    the chain view, where the full fill searches in both of its levels."""
+    from babble_tpu.ops.state import SORTED_BLOCK
+
+    eng = _assert_fd_full_equals_incremental(4, 2600, 31, 4096, 1024, 256)
+    assert int(np.asarray(eng.state.cnt).max()) > 4 * SORTED_BLOCK

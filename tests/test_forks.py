@@ -138,24 +138,43 @@ def test_fork_budget_rejects_spam():
     assert len(fh6.consensus_events()) > 0
 
 
-def test_fd_reverse_matches_chain_counts():
-    """Both fork fd strategies (reverse level scan vs chain-view compare-
-    count) must produce identical tensors."""
+def _assert_fd_forms_agree(cfg, batch):
     import jax
     import numpy as np
 
     from babble_tpu.ops import forks as F
 
+    la = jax.jit(lambda b: F._la_scan(cfg, b))(batch)
+    a = np.asarray(jax.jit(lambda b: F._fd_reverse(cfg, b))(batch))
+    c = np.asarray(jax.jit(lambda b: F._fd_chains(cfg, b, la))(batch))
+    assert (a == c).all(), f"{int((a != c).sum())} fd mismatches"
+
+
+def test_fd_reverse_matches_chain_counts():
+    """Both fork fd strategies (reverse level scan vs chain-view search)
+    must produce identical tensors."""
     dag = random_byzantine_dag(7, 300, seed=9, fork_rate=0.08)
     fh = ForkHashgraph(dag.participants, k=2)
     for ev in dag.events:
         fh.insert_event(ev.clone())
     cfg, _ = fh._run()
-    batch = fh.dag.build_batch(cfg)
-    la = jax.jit(lambda b: F._la_scan(cfg, b))(batch)
-    a = np.asarray(jax.jit(lambda b: F._fd_reverse(cfg, b))(batch))
-    c = np.asarray(jax.jit(lambda b: F._fd_chains(cfg, b, la))(batch))
-    assert (a == c).all(), f"{int((a != c).sum())} fd mismatches"
+    _assert_fd_forms_agree(cfg, fh.dag.build_batch(cfg))
+
+
+@pytest.mark.parametrize("seed", [2, 5])
+def test_fd_reverse_matches_chain_counts_several_blocks(seed):
+    """The same at chains that span several 128-position blocks of the
+    chain view (4 creators, ~650 events each, one fork), where the
+    search takes both of its levels."""
+    from babble_tpu.ops.state import SORTED_BLOCK
+    from babble_tpu.sim.arrays import random_byzantine_fork_batch
+
+    cfg, batch = random_byzantine_fork_batch(4, 2600, seed=seed,
+                                             fork_rate=0.01)
+    assert cfg.s_cap + 1 > 4 * SORTED_BLOCK
+    assert int(batch.cnt.max()) > 4 * SORTED_BLOCK
+    assert int((batch.cnt > 0).sum()) == cfg.n + 1         # one fork
+    _assert_fd_forms_agree(cfg, batch)
 
 
 def _fresh_rounds(cfg, batch):

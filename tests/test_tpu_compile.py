@@ -224,3 +224,44 @@ def test_fork_pipeline_fits_one_chip(one_chip, chip_compile):
     compiled = jax.jit(functools.partial(fork_pipeline_impl, cfg)).lower(
         _abstract(batch, one_chip)).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+#: 1% of one chip's HBM: the most either first-descendant fill may keep
+#: as temporaries at the benchmark cells' shape
+FD_TEMP_BYTES = V5E_HBM_BYTES // 100
+
+
+@pytest.mark.parametrize("fill", ["fork", "honest"])
+def test_fd_fill_temporaries_at_the_cells_shape(one_chip, chip_compile,
+                                                fill):
+    """Both first-descendant fills at the benchmark cells' shape (4 x
+    65,536 events, chains of 16,386 positions; the fork cell's k = 2)
+    keep under 1% of the chip as temporaries: the chain-view search's
+    chunks stay bounded."""
+    from babble_tpu.ops import forks as F
+    from babble_tpu.ops.ingest import _fd_full
+    from babble_tpu.ops.state import DagConfig, init_state
+    from babble_tpu.sim.arrays import random_byzantine_fork_batch
+
+    e_cap, s_cap = 65_536, 16_385
+    if fill == "fork":
+        cfg = F.ForkConfig(n=4, k=2, e_cap=e_cap, s_cap=s_cap, r_cap=4224)
+        _, tiny = random_byzantine_fork_batch(4, 64, seed=1, r_cap=16)
+        e1, b, s1 = e_cap + 1, cfg.b, s_cap + 1
+        shapes = dict(sp=(e1,), op=(e1,), ebr=(e1,), eseq=(e1,), ecr=(e1,),
+                      ts=(e1,), mbit=(e1,), sched=(38_912, b), cp=(b, b),
+                      ce=(b, s1), cnt=(b,), owner=(b, s1), n_events=(),
+                      rseed=(e1,), wseed=(e1,), s_off=(b,))
+        batch = F.ForkBatch(**{
+            f: jax.ShapeDtypeStruct(shapes[f], getattr(tiny, f).dtype,
+                                    sharding=one_chip)
+            for f in F.ForkBatch._fields})
+        la = jax.ShapeDtypeStruct((e1, b), jnp.int32, sharding=one_chip)
+        lowered = jax.jit(lambda bt, l: F._fd_chains(cfg, bt, l)).lower(
+            batch, la)
+    else:
+        cfg = DagConfig(n=4, e_cap=e_cap, s_cap=s_cap, r_cap=4224)
+        state = _abstract(jax.eval_shape(lambda: init_state(cfg)), one_chip)
+        lowered = jax.jit(lambda st: _fd_full(st, cfg)).lower(state)
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < FD_TEMP_BYTES, temp
