@@ -628,28 +628,42 @@ def _fd_reverse(cfg: ForkConfig, b: ForkBatch) -> jnp.ndarray:
     return set_sentinel(fd, e_row, INT32_MAX)
 
 
+#: elements of one chain chunk's view slab [Cb, S+1, B] in ``_fd_chains``
+#: (512 MB of i32): the whole view at once needs 17.2 GB at 1,024
+#: validators x 2 branch slots, 131,072 events, a schedule deep enough
+#: for this form (described v5e); the cells' shape is one chunk
+_CHAIN_CHUNK_ELEMS = 2 ** 27
+
+
 def _fd_chains(cfg: ForkConfig, b: ForkBatch, la: jnp.ndarray) -> jnp.ndarray:
     """fd[y, br] = first chain-(br) index of a descendant of y: the count,
     on chain br, of positions whose event is not yet a descendant of y,
     over the monotone chain view (state.count_below_sorted, the _fd_full
-    pattern with a branch axis).  V is k*E*B*4 bytes, small wherever the
-    cost model picks this form over _fd_reverse."""
+    pattern with a branch axis).  The chain axis runs in chunks of
+    ``_CHAIN_CHUNK_ELEMS``; each fills its own fd columns."""
+    B = cfg.b
     s_idx = jnp.arange(cfg.s_cap + 1)
-    live = s_idx[None, :] < b.cnt[:, None]                    # [B, S+1]
-    V = jnp.where(live[:, :, None], la[sanitize(b.ce, cfg.e_cap)],
-                  INT32_MAX)                                  # [B, S+1, B]
     # thresholds are ABSOLUTE target-chain indexes (window position t on
     # chain `by` is index t + s_off[by])
-    out = count_below_sorted(V, s_idx[None, :] + b.s_off[:, None])
-    found = out < b.cnt[:, None, None]
-    # counts are window positions on the source chain -> absolute
-    out = jnp.where(found, out + b.s_off[:, None, None], INT32_MAX)
-
-    # fd[ce[by, t], br] = out[br, by, t] over the owned-target grid (rows
-    # disjoint across chains)
+    thr = s_idx[None, :] + b.s_off[:, None]                   # [B, S+1]
+    # owned-target grid (rows disjoint across chains)
     tgt = sanitize(jnp.where(b.owner, b.ce, -1), cfg.e_cap)   # [B, S+1]
-    fd = jnp.full((cfg.e_cap + 1, cfg.b), INT32_MAX, I32)
-    fd = fd.at[tgt].set(out.transpose(1, 2, 0))               # [B, T, B]
+    cb = max(1, min(B, _CHAIN_CHUNK_ELEMS // ((cfg.s_cap + 1) * B)))
+    blocks = []
+    for c0 in range(0, B, cb):
+        ce, cnt = b.ce[c0:c0 + cb], b.cnt[c0:c0 + cb]
+        live = s_idx[None, :] < cnt[:, None]                  # [Cb, S+1]
+        V = jnp.where(live[:, :, None], la[sanitize(ce, cfg.e_cap)],
+                      INT32_MAX)                              # [Cb, S+1, B]
+        out = count_below_sorted(V, thr)                      # [Cb, B, T]
+        found = out < cnt[:, None, None]
+        # counts are window positions on the source chain -> absolute
+        out = jnp.where(found, out + b.s_off[c0:c0 + cb, None, None],
+                        INT32_MAX)
+        # fd[ce[by, t], br] = out[br, by, t]
+        blk = jnp.full((cfg.e_cap + 1, out.shape[0]), INT32_MAX, I32)
+        blocks.append(blk.at[tgt].set(out.transpose(1, 2, 0)))
+    fd = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
     e_row = (jnp.arange(cfg.e_cap + 1) == cfg.e_cap)[:, None]
     return set_sentinel(fd, e_row, INT32_MAX)
 

@@ -476,10 +476,15 @@ def bucket(x: int, minimum: int = 8) -> int:
 def fd_reverse_scan_wins(sched_rows: int, e_cap: int, k: int = 1) -> bool:
     """Measured v5e cost model for the two first-descendant strategies:
     the reverse level scan pays ~25 us per schedule row; the chain-view
-    compare-count pays ~(k*E)^2 / 3e10 s (k = branch slots per creator —
+    count was fitted at ~(k*E)^2 / 3e10 s (k = branch slots per creator —
     the fork pipeline's column axis is k*N wide).  Deep narrow DAGs favor
     the count, wide ones the scan (measured: 64x65k 3,494 levels -> count;
-    1024x100k 392 levels and 256x1M -> scan, 12x at 1M)."""
+    1024x100k 392 levels and 256x1M -> scan, 12x at 1M).  The count's
+    estimate is stale: it was fitted to the plain compare-count over
+    every chain position, and ``count_below_sorted`` now searches in two
+    levels at a fraction of that for chains longer than one block, so
+    the line sends some deep shapes to the scan that the search would
+    beat (PERF.md section 7 item 8; not re-fitted)."""
     return sched_rows < ((k * e_cap) ** 2) * 4.8e-7
 
 
@@ -495,43 +500,37 @@ def count_below_sorted(V: jnp.ndarray, thr: jnp.ndarray) -> jnp.ndarray:
     non-decreasing along s (the chain view of both first-descendant
     fills: la along a chain only grows, INT32_MAX past its tail).
 
-    A search in two levels over blocks of ``SORTED_BLOCK`` positions:
-    count the block maxima below each threshold (``m`` whole blocks lie
-    below it), then count inside the one block where it lands, fetched
-    as a single row (per-element gathers run one at a time on TPU; a row
-    of 128 lanes is one fetch): nb + 128 compares per threshold, nb the
-    blocks, instead of S+1.  A column of one block takes the plain count
-    (the 1,024 x 100,000 shape's chains are ~99 long).  Both levels
-    run in chunks of thresholds so no intermediate exceeds
-    ``_COUNT_CHUNK_ELEMS``.  Equal to the plain count bit for bit."""
+    A search in two levels over blocks of at most ``SORTED_BLOCK``
+    positions: count the block maxima below each threshold (``m`` whole
+    blocks lie below it), then count inside the one block where it
+    lands, fetched as a single row (per-element gathers run one at a
+    time on TPU; a row of 128 lanes is one fetch): nb + 128 compares per
+    threshold, nb the blocks, instead of S+1.  A column no longer than
+    one block is one unpadded row, so there the fine level is the plain
+    count.  Both levels run in chunks of thresholds so no intermediate
+    exceeds ``_COUNT_CHUNK_ELEMS``.  Equal to the plain count bit for
+    bit."""
     c, s1, d = V.shape
     t_total = thr.shape[1]
     thr = thr.astype(I32)
-    lanes = SORTED_BLOCK
+    lanes = min(SORTED_BLOCK, s1)
     nb = -(-s1 // lanes)
-    if nb == 1:
-        per_t = c * s1 * d
+    rows = jnp.pad(jnp.swapaxes(V, 1, 2),
+                   ((0, 0), (0, 0), (0, nb * lanes - s1)),
+                   constant_values=INT32_MAX)
+    rows = rows.reshape(c * d * nb, lanes)                     # [C*D*nb, L]
+    bmax = rows[:, lanes - 1].reshape(c, d, nb)
+    base = (jnp.arange(c * d, dtype=I32) * nb).reshape(c, d, 1)
 
-        def count_chunk(th):                                   # [D, Tc]
-            return (V[:, :, :, None] < th[None, None]).sum(axis=1, dtype=I32)
-    else:
-        per_t = c * d * max(nb, lanes)
-        rows = jnp.pad(jnp.swapaxes(V, 1, 2),
-                       ((0, 0), (0, 0), (0, nb * lanes - s1)),
-                       constant_values=INT32_MAX)
-        rows = rows.reshape(c * d * nb, lanes)                 # [C*D*nb, L]
-        bmax = rows[:, lanes - 1].reshape(c, d, nb)
-        base = (jnp.arange(c * d, dtype=I32) * nb).reshape(c, d, 1)
+    def count_chunk(th):                                       # [D, Tc]
+        m = (bmax[:, :, :, None] < th[None, :, None, :]).sum(
+            axis=2, dtype=I32)                                 # [C, D, Tc]
+        blk = jnp.minimum(m, nb - 1)
+        row = rows[base + blk]                                 # [C, D, Tc, L]
+        fine = (row < th[None, :, :, None]).sum(axis=3, dtype=I32)
+        return blk * lanes + fine
 
-        def count_chunk(th):                                   # [D, Tc]
-            m = (bmax[:, :, :, None] < th[None, :, None, :]).sum(
-                axis=2, dtype=I32)                             # [C, D, Tc]
-            blk = jnp.minimum(m, nb - 1)
-            row = rows[base + blk]                             # [C, D, Tc, L]
-            fine = (row < th[None, :, :, None]).sum(axis=3, dtype=I32)
-            return blk * lanes + fine
-
-    tc = max(1, min(t_total, _COUNT_CHUNK_ELEMS // per_t))
+    tc = max(1, min(t_total, _COUNT_CHUNK_ELEMS // (c * d * max(nb, lanes))))
     n_chunks = -(-t_total // tc)
     thr_p = jnp.pad(thr, ((0, 0), (0, n_chunks * tc - t_total)))
     chunks = thr_p.reshape(d, n_chunks, tc).swapaxes(0, 1)    # [n, D, Tc]

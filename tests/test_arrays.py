@@ -90,7 +90,11 @@ def test_fd_modes_match_full(fd_mode):
 @pytest.mark.parametrize("fd_mode", ["fast", "incremental"])
 def test_fd_modes_match_full_several_blocks(fd_mode):
     """The same at chains of ~650 events, several 128-position blocks of
-    the chain view, where 'full' searches in both of its levels."""
+    the chain view, where 'full' searches in both of its levels.  At
+    this shape 'fast' picks the same search itself
+    (``fd_reverse_scan_wins`` is False), so [fast] checks the rest of
+    its path;
+    [incremental] is the independent check of the search."""
     from babble_tpu.ops.state import SORTED_BLOCK
 
     cfg = _assert_fd_mode_matches_full(fd_mode, 4, 2600, 5, 256)
@@ -128,7 +132,7 @@ def test_count_below_sorted_matches_plain_count(s1, offset):
 @pytest.mark.parametrize("s1", [100, 700])
 def test_count_below_sorted_in_chunks(monkeypatch, s1):
     """The same with the threshold axis cut into many chunks, a last one
-    partly padding (nb = 1 takes the plain count, nb > 1 the search)."""
+    partly padding (a column of one block, 100, and of six, 700)."""
     import jax
 
     from babble_tpu.ops import state

@@ -231,6 +231,35 @@ def test_fork_pipeline_fits_one_chip(one_chip, chip_compile):
 FD_TEMP_BYTES = V5E_HBM_BYTES // 100
 
 
+def _abstract_fork_batch(cfg, sched_rows, sharding):
+    """A ForkBatch of abstract arrays at ``cfg``'s capacities (dtypes
+    from a tiny random batch), with a schedule of ``sched_rows`` rows."""
+    from babble_tpu.ops import forks as F
+    from babble_tpu.sim.arrays import random_byzantine_fork_batch
+
+    _, tiny = random_byzantine_fork_batch(4, 64, seed=1, r_cap=16)
+    e1, b, s1 = cfg.e_cap + 1, cfg.b, cfg.s_cap + 1
+    shapes = dict(sp=(e1,), op=(e1,), ebr=(e1,), eseq=(e1,), ecr=(e1,),
+                  ts=(e1,), mbit=(e1,), sched=(sched_rows, b), cp=(b, b),
+                  ce=(b, s1), cnt=(b,), owner=(b, s1), n_events=(),
+                  rseed=(e1,), wseed=(e1,), s_off=(b,))
+    return F.ForkBatch(**{
+        f: jax.ShapeDtypeStruct(shapes[f], getattr(tiny, f).dtype,
+                                sharding=sharding)
+        for f in F.ForkBatch._fields})
+
+
+def _fork_fd_chains_temp(cfg, sched_rows, sharding):
+    from babble_tpu.ops import forks as F
+
+    batch = _abstract_fork_batch(cfg, sched_rows, sharding)
+    la = jax.ShapeDtypeStruct((cfg.e_cap + 1, cfg.b), jnp.int32,
+                              sharding=sharding)
+    lowered = jax.jit(lambda bt, l: F._fd_chains(cfg, bt, l)).lower(
+        batch, la)
+    return lowered.compile().memory_analysis().temp_size_in_bytes
+
+
 @pytest.mark.parametrize("fill", ["fork", "honest"])
 def test_fd_fill_temporaries_at_the_cells_shape(one_chip, chip_compile,
                                                 fill):
@@ -241,27 +270,30 @@ def test_fd_fill_temporaries_at_the_cells_shape(one_chip, chip_compile,
     from babble_tpu.ops import forks as F
     from babble_tpu.ops.ingest import _fd_full
     from babble_tpu.ops.state import DagConfig, init_state
-    from babble_tpu.sim.arrays import random_byzantine_fork_batch
 
     e_cap, s_cap = 65_536, 16_385
     if fill == "fork":
         cfg = F.ForkConfig(n=4, k=2, e_cap=e_cap, s_cap=s_cap, r_cap=4224)
-        _, tiny = random_byzantine_fork_batch(4, 64, seed=1, r_cap=16)
-        e1, b, s1 = e_cap + 1, cfg.b, s_cap + 1
-        shapes = dict(sp=(e1,), op=(e1,), ebr=(e1,), eseq=(e1,), ecr=(e1,),
-                      ts=(e1,), mbit=(e1,), sched=(38_912, b), cp=(b, b),
-                      ce=(b, s1), cnt=(b,), owner=(b, s1), n_events=(),
-                      rseed=(e1,), wseed=(e1,), s_off=(b,))
-        batch = F.ForkBatch(**{
-            f: jax.ShapeDtypeStruct(shapes[f], getattr(tiny, f).dtype,
-                                    sharding=one_chip)
-            for f in F.ForkBatch._fields})
-        la = jax.ShapeDtypeStruct((e1, b), jnp.int32, sharding=one_chip)
-        lowered = jax.jit(lambda bt, l: F._fd_chains(cfg, bt, l)).lower(
-            batch, la)
+        temp = _fork_fd_chains_temp(cfg, 38_912, one_chip)
     else:
         cfg = DagConfig(n=4, e_cap=e_cap, s_cap=s_cap, r_cap=4224)
         state = _abstract(jax.eval_shape(lambda: init_state(cfg)), one_chip)
         lowered = jax.jit(lambda st: _fd_full(st, cfg)).lower(state)
-    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+        temp = lowered.compile().memory_analysis().temp_size_in_bytes
     assert temp < FD_TEMP_BYTES, temp
+
+
+def test_fork_fd_chains_fits_wide_and_deep(one_chip, chip_compile):
+    """The fork pipeline's chain-view fill where the cost model sends a
+    wide DAG to it (1,024 validators x 2 branch slots, 131,072 events,
+    a schedule just deep enough) keeps under a quarter of the chip as
+    temporaries: its chain axis runs in chunks.  The whole [B, S+1, B]
+    view at once kept 17.2 GB."""
+    from babble_tpu.ops import forks as F
+    from babble_tpu.ops.state import fd_reverse_scan_wins
+
+    cfg = F.ForkConfig(n=1024, k=2, e_cap=131_072, s_cap=256, r_cap=16)
+    rows = int((cfg.k * cfg.e_cap) ** 2 * 4.8e-7) + 1
+    assert not fd_reverse_scan_wins(rows, cfg.e_cap, cfg.k)
+    temp = _fork_fd_chains_temp(cfg, rows, one_chip)
+    assert temp < V5E_HBM_BYTES // 4, temp
